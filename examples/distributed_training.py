@@ -11,11 +11,17 @@ deterministically, since view i depends only on (seed, i) — and
 ``assert_compiled_once()`` certifies that no strategy switch ever
 retraced the step.
 
-    PYTHONPATH=src python examples/distributed_training.py [--steps 200]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/distributed_training.py
+    PYTHONPATH=src python examples/distributed_training.py --workers 4  # TPU
+
+On the CPU, eight fake host devices stand in for the worker group; on an
+accelerator host the workers are its chips.
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import argparse
 import time
@@ -38,9 +44,10 @@ def main():
     ap.add_argument("--workers", type=int, default=8)
     ap.add_argument("--partition", default="1d_src",
                     choices=["1d_src", "1d_dst", "vertex_cut"])
-    ap.add_argument("--backend", default="reference",
+    ap.add_argument("--backend", default=None,
                     choices=["reference", "csc"],
-                    help="Sum-stage aggregation backend")
+                    help="Sum-stage aggregation backend (default: csc on "
+                    "TPU, reference elsewhere)")
     ap.add_argument("--no-prefetch", action="store_true",
                     help="disable the host-side view prefetch pipeline")
     ap.add_argument("--prefetch-workers", type=int, default=None,

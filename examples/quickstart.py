@@ -8,6 +8,7 @@ import argparse
 import jax
 
 from repro.config import GNNConfig
+from repro.core.aggregate import backend_name
 from repro.core.mpgnn import accuracy_block, loss_block
 from repro.core.strategies import global_batch_view
 from repro.graph import make_dataset
@@ -15,7 +16,7 @@ from repro.models import make_gnn
 from repro.optim import adam
 
 
-def main(backend: str = "reference"):
+def main(backend=None):
     g = make_dataset("cora", seed=0).add_self_loops()
     cfg = GNNConfig(model="gcn", num_layers=2, hidden_dim=32, num_classes=7,
                     feature_dim=g.node_features.shape[1],
@@ -25,7 +26,7 @@ def main(backend: str = "reference"):
     opt = adam(1e-2, weight_decay=5e-4)
     state = opt.init(params)
     block = global_batch_view(g, cfg.num_layers).as_block(
-        csc_plan=backend == "csc")
+        csc_plan=backend_name(model) == "csc")
 
     @jax.jit
     def step(params, state):
@@ -58,7 +59,8 @@ def main(backend: str = "reference"):
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--backend", default="reference",
+    ap.add_argument("--backend", default=None,
                     choices=["reference", "csc"],
-                    help="Sum-stage aggregation backend")
+                    help="Sum-stage aggregation backend (default: csc on "
+                    "TPU, reference elsewhere)")
     main(ap.parse_args().backend)

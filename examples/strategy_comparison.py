@@ -14,11 +14,16 @@ through the identical compiled step (an O(view) shard scatter instead of
 dense-mask gathers) — same trajectory, a fraction of the per-view host
 bytes.
 
-    PYTHONPATH=src python examples/strategy_comparison.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/strategy_comparison.py
+
+On the CPU, four fake host devices stand in for the workers; on an
+accelerator host the workers are its chips (up to four).
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=4")
 
 import time
 

@@ -153,9 +153,10 @@ def jaxpr_eqns(closed_jaxpr, skip_pallas_bodies: bool = False):
                         stack.append(inner)
 
 
-def jaxpr_avals(closed_jaxpr):
-    """Yield the output aval of every equation, recursing into sub-jaxprs."""
-    for eqn in jaxpr_eqns(closed_jaxpr):
+def jaxpr_avals(closed_jaxpr, skip_pallas_bodies: bool = False):
+    """Yield the output aval of every equation, recursing into sub-jaxprs
+    (``skip_pallas_bodies`` as in :func:`jaxpr_eqns`)."""
+    for eqn in jaxpr_eqns(closed_jaxpr, skip_pallas_bodies):
         for var in eqn.outvars:
             yield var.aval
 
@@ -274,7 +275,9 @@ def _check_pregather(ctx: JaxprContext) -> List[Finding]:
         return []
     nb, l_pad = ctx.plan.gather_idx.shape[-2:]
     findings = []
-    for aval in jaxpr_avals(ctx.closed_jaxpr):
+    # kernel-body values are VMEM tiles, not HBM tensors: a (1, BE) tile
+    # collides with (nb, L_pad) when nb == 1 and L_pad == BE
+    for aval in jaxpr_avals(ctx.closed_jaxpr, skip_pallas_bodies=True):
         shape = tuple(getattr(aval, "shape", ()))
         if len(shape) < 2 or shape[:2] != (nb, l_pad):
             continue

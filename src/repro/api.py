@@ -246,12 +246,12 @@ def infer(result: TrainResult,
     requested nodes' rows. For sustained request traffic use
     :func:`serve` — batching, bucketed compilation and the embedding
     cache live there."""
+    from repro.core.aggregate import backend_name
     from repro.core.mpgnn import forward_block
     from repro.core.strategies import global_batch_view
     model, g = result.model, result.graph
     block = global_batch_view(g, model.K).as_block(
-        gcn_norm=result.gcn_norm,
-        csc_plan=getattr(model, "aggregate_backend", "reference") == "csc")
+        gcn_norm=result.gcn_norm, csc_plan=backend_name(model) == "csc")
     logits = np.asarray(forward_block(model, result.params, block))
     logits = logits[:g.num_nodes]
     if nodes is None:

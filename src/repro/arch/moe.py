@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.nn.layers import _fan_in_init
-from repro.utils.compat import shard_map
 
 
 def moe_init(key, d_model, d_ff, num_experts, dtype):
@@ -131,9 +130,9 @@ def moe_ffn_ep(p, x, moe_cfg, mesh, axis: str = "model", dp_axis=None):
         wi_g, wi_u, wo = zp(wi_g), zp(wi_u), zp(wo)
 
     x_spec = P(dp_axis, axis, None)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, x_spec, P(axis), P(axis), P(axis)),
-        out_specs=x_spec,
+        out_specs=x_spec, check_vma=False,
     )(x, gates.astype(x.dtype), wi_g, wi_u, wo)
     return out.astype(x.dtype), aux

@@ -280,8 +280,9 @@ class GNNConfig:
     residual: bool = False
     mean_aggregate: bool = True     # mean vs sum neighbor aggregation
     # Sum-stage aggregation backend: "reference" (jnp segment ops) or
-    # "csc" (Pallas CSC-blocked kernels; see repro.core.aggregate)
-    aggregate_backend: str = "reference"
+    # "csc" (Pallas CSC-blocked kernels; see repro.core.aggregate). None
+    # lets the platform pick: "csc" on TPU, "reference" elsewhere
+    aggregate_backend: Optional[str] = None
 
 
 @dataclass(frozen=True)

@@ -10,9 +10,12 @@ engine both consume:
 - :data:`COMBINE_SPECS` — the registry of combine modes (``sum`` / ``mean``
   / ``max`` / ``softmax``) with their algebraic properties.
 - :class:`AggregationBackend` — pluggable segment primitives. Two
-  implementations ship: ``"reference"`` (portable jnp segment ops) and
-  ``"csc"`` (the Pallas CSC-blocked kernels of :mod:`repro.kernels`,
-  interpret-mode on CPU, Mosaic on TPU), selected by name from config.
+  implementations ship: ``"reference"`` (portable jnp segment ops, the
+  float32 oracle) and ``"csc"`` (the Pallas CSC-blocked kernels of
+  :mod:`repro.kernels`, interpret-mode on CPU, Mosaic on TPU). A name
+  given in config is honoured; without one the platform picks
+  (:func:`default_backend_name`: ``"csc"`` on TPU, ``"reference"``
+  elsewhere).
 - :func:`combine` — the one Sum-stage implementation. Locally it is the
   full aggregation; under the hybrid-parallel engine the same code runs on
   shard-local partials and finalizes through a :class:`ShardContext`
@@ -21,13 +24,12 @@ engine both consume:
 
 The ``"csc"`` backend needs a precomputed :class:`~repro.kernels.ops.
 CSCPlan` (built once per graph/shard — the paper's reused CSC indexing);
-when no plan is threaded through it falls back to the reference primitives
-so exotic callers (e.g. the explicit-autodiff reference schedule) keep
-working. The plan's index arrays ride into the kernels as scalar-prefetch
-operands and the per-edge gather happens on-chip — the kernel path
-consumes the raw ``(E, H, D)`` messages directly, with no pre-gathered
-``(nb, L_pad, D)`` intermediate (and multi-head softmax is one launch,
-heads on the kernel grid). Kernel forwards are paired with fused Pallas
+a call without one raises :class:`MissingPlanError` rather than quietly
+running the reference ops. The kernels read the plan's index chunks into
+SMEM and gather message rows from HBM by DMA — the kernel path consumes
+the raw ``(E, H, D)`` messages directly, with no pre-gathered
+``(nb, L_pad, D)`` intermediate (and multi-head softmax is one launch).
+Kernel forwards are paired with fused Pallas
 ``custom_vjp`` backwards (:mod:`repro.kernels.backward`): a plan-driven
 gather kernel for sum, the same gather plus an in-kernel argmax-hit mask
 for max, and a recompute-in-kernel softmax jacobian — so ``jax.grad`` of
@@ -44,7 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ops import (CSCPlan, edge_softmax_bwd_op,
+from repro.kernels.ops import (CSCPlan, default_interpret,
+                               edge_softmax_bwd_op,
                                edge_softmax_fwd_op, edge_softmax_op,
                                segment_max_bwd_op, segment_max_op,
                                segment_sum_bwd_op, segment_sum_op)
@@ -275,13 +278,17 @@ def reference_edge_softmax_bwd(g, logits, values, out, segment_ids,
     return d_logits, d_values
 
 
+class MissingPlanError(ValueError):
+    """The ``"csc"`` backend was called without a CSCPlan."""
+
+
 class CSCBackend(AggregationBackend):
     """The Pallas CSC-blocked kernels behind the backend interface.
 
-    Requires a precomputed CSCPlan for the kernel path (build once per
-    graph/shard via ``GraphBlock``/``PartitionPlan`` caches); without one
-    it degrades to the reference primitives. ``interpret=None`` resolves
-    per call: interpret-mode off TPU, Mosaic compilation on TPU.
+    Requires a precomputed CSCPlan (build once per graph/shard via
+    ``GraphBlock``/``PartitionPlan`` caches); a call without one raises
+    :class:`MissingPlanError`. ``interpret=None`` resolves per call:
+    interpret-mode off TPU, Mosaic compilation on TPU.
     """
 
     name = "csc"
@@ -289,13 +296,15 @@ class CSCBackend(AggregationBackend):
     def __init__(self, interpret: Optional[bool] = None):
         self.interpret = interpret
 
-    def _interp(self) -> bool:
-        if self.interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.interpret
-
-    def _meta(self, plan: CSCPlan):
-        return (plan.block_n, plan.block_e, self._interp())
+    def _meta(self, plan: Optional[CSCPlan]):
+        if plan is None:
+            raise MissingPlanError(
+                "the csc backend needs a CSCPlan: build the block with "
+                "csc_plan=True (or stage it through the engine), or pick "
+                "the reference backend")
+        interp = (default_interpret() if self.interpret is None
+                  else self.interpret)
+        return (plan.block_n, plan.block_e, interp)
 
     @staticmethod
     def _children(plan: CSCPlan):
@@ -303,22 +312,15 @@ class CSCBackend(AggregationBackend):
                 jnp.asarray(plan.edge_dst))
 
     def segment_sum(self, data, segment_ids, num_segments, plan=None):
-        if plan is None:
-            return jax.ops.segment_sum(data, segment_ids, num_segments)
         return _csc_segment_sum(num_segments, self._meta(plan), data,
                                 self._children(plan), segment_ids)
 
     def segment_max(self, data, segment_ids, num_segments, plan=None):
-        if plan is None:
-            return jax.ops.segment_max(data, segment_ids, num_segments)
         return _csc_segment_max(num_segments, self._meta(plan), data,
                                 self._children(plan), segment_ids)
 
     def edge_softmax(self, logits, values, segment_ids, num_segments,
                      plan=None):
-        if plan is None:
-            return super().edge_softmax(logits, values, segment_ids,
-                                        num_segments, plan)
         return _csc_edge_softmax(num_segments, self._meta(plan), logits,
                                  values, self._children(plan), segment_ids)
 
@@ -336,11 +338,18 @@ register_backend("reference", ReferenceBackend)
 register_backend("csc", CSCBackend)
 
 
+def default_backend_name() -> str:
+    """The platform's Sum-stage backend: the kernels on a TPU, the jnp
+    reference ops elsewhere (where the kernels would only interpret)."""
+    return "csc" if jax.default_backend() == "tpu" else "reference"
+
+
 def get_backend(backend: Union[None, str, AggregationBackend]
                 ) -> AggregationBackend:
-    """Resolve a backend name (or pass an instance through)."""
+    """Resolve a backend name (or pass an instance through); ``None`` is
+    the platform's default (:func:`default_backend_name`)."""
     if backend is None:
-        backend = "reference"
+        backend = default_backend_name()
     if isinstance(backend, AggregationBackend):
         return backend
     if backend not in _BACKENDS:
@@ -349,6 +358,12 @@ def get_backend(backend: Union[None, str, AggregationBackend]
     if backend not in _INSTANCES:
         _INSTANCES[backend] = _BACKENDS[backend]()
     return _INSTANCES[backend]
+
+
+def backend_name(model) -> str:
+    """The resolved backend name of a model (its ``aggregate_backend``,
+    or the platform default when it names none)."""
+    return get_backend(getattr(model, "aggregate_backend", None)).name
 
 
 # ---------------------------------------------------------------------------

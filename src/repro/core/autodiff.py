@@ -59,7 +59,9 @@ def explicit_loss_and_grad(model: MPGNNModel, params, block):
         msg, g_vjp = jax.vjp(g_fn, lp, n)
 
         def s_fn(msg_, layer_=layer, em_=em):
-            return combine_messages(layer_, msg_, block.dst, n_pad, em_)
+            return combine_messages(layer_, msg_, block.dst, n_pad, em_,
+                                    backend=model.aggregate_backend,
+                                    plan=getattr(block, "csc_plan", None))
         M, s_vjp = jax.vjp(s_fn, msg)
 
         def a_fn(p_, h_, M_, layer_=layer, na_=na):

@@ -36,12 +36,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.aggregate import ShardContext, combine, get_backend
+from repro.core.aggregate import (ShardContext, backend_name, combine,
+                                   get_backend)
 from repro.core.mpgnn import MPGNNModel
 from repro.core.partition import ShardedGraph
 from repro.core.tgar import TGARLayer, tree_take, NEG
 from repro.kernels.ops import CSCPlan
-from repro.utils.compat import shard_map
 
 Axis = str
 
@@ -172,9 +172,8 @@ class HybridParallelEngine:
         self.sg = sharded
         self.plan = sharded.plan
         self.axis = axis
-        if backend is None:
-            backend = getattr(model, "aggregate_backend", "reference")
-        self.backend = get_backend(backend)
+        self.backend = get_backend(backend if backend is not None
+                                   else backend_name(model))
         self._csc_meta = None
         if mesh is None:
             devs = np.array(jax.devices()[: self.plan.P])
@@ -312,10 +311,10 @@ class HybridParallelEngine:
                 grads = jax.lax.psum(grads, self.axis)
                 return loss, grads
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(), specs_data, specs_view),
-                out_specs=(P(), P()),
+                out_specs=(P(), P()), check_vma=False,
             )(params, data, view)
 
         return fn
@@ -354,10 +353,10 @@ class HybridParallelEngine:
                 logits = self._forward_local(params, shard)
                 return logits[None]
 
-            return shard_map(
+            return jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(), specs_data, specs_view),
-                out_specs=P(self.axis),
+                out_specs=P(self.axis), check_vma=False,
             )(params, data, view)
 
         def fn(params, view_arrays):

@@ -10,7 +10,7 @@ unified implementation".
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -25,9 +25,10 @@ class MPGNNModel:
     num_classes: int
     decoder_hidden: int = 0          # optional extra FC before the decoder
     # Sum-stage aggregation backend ("reference" | "csc", see
-    # repro.core.aggregate); the "csc" kernel path additionally needs a
-    # CSCPlan on the block (build_block(csc_plan=True)) or engine shard
-    aggregate_backend: str = "reference"
+    # repro.core.aggregate; None = the platform's default). The "csc"
+    # kernel path needs a CSCPlan on the block (build_block(csc_plan=
+    # True)) or engine shard
+    aggregate_backend: Optional[str] = None
 
     @property
     def K(self):

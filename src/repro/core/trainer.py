@@ -65,8 +65,10 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
+from repro.core.aggregate import backend_name
 from repro.core.strategies import GraphView, shard_view
 from repro.core.views import CompactBlockBuilder, ViewStream
 from repro.runtime.faults import (DivergenceError, FaultInjector,
@@ -246,6 +248,11 @@ class BaseTrainer:
 
     def _on_reset(self) -> None:
         """Subclass-specific reset extras (e.g. eval caches)."""
+
+    def _place(self, tree):
+        """Device placement of params / optimizer state before they enter
+        the compiled step (identity unless the step pins a sharding)."""
+        return tree
 
     def evaluate(self, view, mask: Optional[np.ndarray] = None) -> float:
         raise NotImplementedError
@@ -489,8 +496,8 @@ class BaseTrainer:
         else:
             ck = rt("checkpoint_load",
                     lambda: load_checkpoint(directory, step))
-        self.params = ck["params"]
-        self.opt_state = ck["opt_state"]
+        self.params = self._place(ck["params"])
+        self.opt_state = self._place(ck["opt_state"])
         self.step_num = int(ck["step"])
         if "view_cursor" in ck:      # older checkpoints predate the key
             self.view_cursor = int(ck["view_cursor"])
@@ -505,8 +512,8 @@ class BaseTrainer:
         strategies and still certify the trace contract)."""
         if params is None:
             params = self._init_params(seed)
-        self.params = params
-        self.opt_state = self.opt.init(params)
+        self.params = self._place(params)
+        self.opt_state = self._place(self.opt.init(params))
         self.step_num = 0
         self.history = []
         self.view_cursor = 0
@@ -535,8 +542,8 @@ class Trainer(BaseTrainer):
         self._init_common(opt, prefetch_depth, fault_policy, injector)
         if params is None:
             params = self._init_params(seed)
-        self.params = params
-        self.opt_state = opt.init(params)
+        self.params = self._place(params)
+        self.opt_state = self._place(opt.init(params))
         self.trace_counts = {"train_step": 0, "infer": 0}
 
         lg = engine.make_loss_and_grad()
@@ -605,6 +612,12 @@ class Trainer(BaseTrainer):
 
     def _on_reset(self) -> None:
         self._eval_cache = None
+
+    def _place(self, tree):
+        # replicated on the engine's mesh: the shard_map step returns
+        # params and opt state with that sharding, so the first call must
+        # see the same input types as every later one or jit traces twice
+        return jax.device_put(tree, NamedSharding(self.engine.mesh, P()))
 
     def assert_trace_contract(self) -> None:
         self.assert_compiled_once()
@@ -710,10 +723,9 @@ class CompactTrainer(BaseTrainer):
         self.model = model
         self.g = g
         self._init_common(opt, prefetch_depth, fault_policy, injector)
-        backend = getattr(model, "aggregate_backend", "reference")
         self.stager = CompactBlockBuilder(
             g, model.K, buckets=buckets, slots=slots, gcn_norm=gcn_norm,
-            csc_plan=(backend == "csc"))
+            csc_plan=backend_name(model) == "csc")
         self.buckets = self.stager.buckets
         if params is None:
             params = self._init_params(seed)

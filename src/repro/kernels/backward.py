@@ -17,14 +17,17 @@ Backward is a *scatter-free* pass when organized over the **edge axis**:
 ``d_data[e] = f(g[dst[e]])`` touches each output row exactly once. The
 grid therefore tiles the (padded) edge axis in ``block_e`` chunks; the
 node-indexed arrays (cotangent ``g``, saved forward output, softmax
-stats) stay resident as constant blocks, and the per-edge destination
-comes from the plan's **inverse map** ``edge_dst`` — built host-side in
-``build_csc_plan`` by inverting ``gather_idx``/``local_ids`` (lane
-``(b, l)`` holds edge ``gather_idx[b, l]`` destined for row
-``b*block_n + local_ids[b, l]``) and scalar-prefetched like the forward
-plan indices. Pad lanes carry ``num_segments`` (clip-gathered; the
-outputs are allocated at the true edge count, so the final partial
-block is an ordinary masked boundary block — no pad copies, no slices).
+stats) stay in HBM and each edge's row is copied into VMEM by a row DMA
+(:func:`~repro.kernels.segment_sum.gather_rows`), addressed by the plan's
+**inverse map** ``edge_dst`` — built host-side in ``build_csc_plan`` by
+inverting ``gather_idx``/``local_ids`` (lane ``(b, l)`` holds edge
+``gather_idx[b, l]`` destined for row ``b*block_n + local_ids[b, l]``).
+Each chunk of ``edge_dst`` is copied to SMEM for the DMA addresses and
+also read as a ``(BE, 1)`` column: lanes whose destination is outside
+the plan (``num_segments``: pad lanes, and edges no block aggregates)
+get a zero cotangent, as the reference segment ops' transpose gives
+them. The outputs are allocated at the true edge count, so the final
+partial block is an ordinary masked boundary block.
 
 Three kernels:
 
@@ -40,15 +43,11 @@ Three kernels:
   node-proportional outputs). No ``(E, H)`` probability tensor is ever
   materialized in HBM and no reference ``segment_max``/``segment_sum``
   recompute runs; ``d_logits`` and ``d_values`` come out of **one**
-  launch with heads on the grid, mirroring the forward.
+  launch, all heads per grid step, mirroring the forward.
 
-VMEM geometry mirrors the forward budget (documented in
-segment_sum.py): per grid step the gather kernels hold the resident
-``(N, BD)`` cotangent block plus a ``(BE, BD)`` output tile; the softmax
-backward holds per-head residents ``(N, D)`` cotangent + four ``(N,)``
-stat columns and ``(BE, D)`` tiles — no ``(BE, BN, BD)`` candidate
-expansion anywhere, so the d-tile cap is looser (128) than the forward
-max kernel's (64).
+VMEM geometry is tabulated with the forward budget in segment_sum.py:
+per grid step a ``(BE, BD)`` gather scratch per gathered operand plus the
+``(BE, BD)`` edge tiles — nothing that scales with N or E.
 """
 from __future__ import annotations
 
@@ -59,7 +58,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.segment_sum import NEG, _pick_block_d
+from repro.kernels.segment_sum import (NEG, _pick_block_d, chunk_view,
+                                       gather_rows, lane_pad, row_view)
+
+
+def lane(x, k: int):
+    """Column ``k`` of a 2-D value as a (rows, 1) column (exact select)."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == k
+    return jnp.sum(jnp.where(hit, x, 0.0), axis=1, keepdims=True)
+
+
+def _check_edge_dst(edge_dst, block_e: int, num_edges: int) -> int:
+    e_pad = edge_dst.shape[0]
+    if e_pad % block_e != 0 or e_pad < num_edges:
+        raise ValueError(
+            f"edge_dst pad {e_pad} must be a block_e={block_e} multiple "
+            f"covering num_edges={num_edges}")
+    return e_pad
+
+
+def _edge_specs(block_e: int, col_index):
+    """The (SMEM-bound) edge_dst chunks in HBM and its (BE, 1) column."""
+    return [pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((block_e, 1), col_index)]
+
+
+def _edge_args(edge_dst, block_e: int):
+    return chunk_view(edge_dst, block_e), edge_dst.reshape(-1, 1)
+
+
+def _live(dst_smem, num_segments: int):
+    """Lanes with a destination row (pad lanes hold num_segments)."""
+    return lambda i: dst_smem[0, i] < num_segments
 
 
 # ---------------------------------------------------------------------------
@@ -67,18 +97,20 @@ from repro.kernels.segment_sum import NEG, _pick_block_d
 # ---------------------------------------------------------------------------
 
 
-def _gather_bwd_kernel(dst_ref, g_ref, out_ref, *, block_e: int):
+def _gather_bwd_kernel(dst_hbm, dst_col, g_hbm, out_ref, dst_smem, gbuf, sem,
+                       *, num_segments: int, block_d: int):
     """One (d_tile, edge_chunk) grid step of ``d_data[e] = g[dst[e]]``.
 
-    dst_ref: (E_pad,) int32 scalar-prefetch — the plan's inverse map
-             (pad lanes hold num_segments; clipped, masked by the
-             boundary write).
-    g_ref:   (N, BD) f32 resident cotangent block.
+    dst_hbm: (E_pad/BE, 1, BE) int32 chunks of the plan's inverse map.
+    dst_col: (BE, 1) int32 — the same chunk as a column (validity).
+    g_hbm:   (N, 1, Dp) f32 cotangent rows in HBM.
     out_ref: (BE, BD) f32 edge tile of the message cotangent.
     """
-    c = pl.program_id(1)
-    idx = dst_ref[pl.ds(c * block_e, block_e)]           # (BE,)
-    out_ref[...] = jnp.take(g_ref[...], idx, axis=0, mode="clip")
+    dt, c = pl.program_id(0), pl.program_id(1)
+    pltpu.sync_copy(dst_hbm.at[c], dst_smem)
+    gather_rows(dst_smem, g_hbm, gbuf, sem, num_segments, dt * block_d,
+                block_d, _live(dst_smem, num_segments))
+    out_ref[...] = jnp.where(dst_col[...] < num_segments, gbuf[...], 0.0)
 
 
 def segment_sum_bwd_csc(g: jax.Array, edge_dst: jax.Array, num_edges: int,
@@ -89,36 +121,35 @@ def segment_sum_bwd_csc(g: jax.Array, edge_dst: jax.Array, num_edges: int,
 
     g:        (N, D) cotangent of the (sliced) kernel output.
     edge_dst: (E_pad,) int32, E_pad % block_e == 0; lane e holds dst[e],
-              pad lanes hold N (clip-gathered, boundary-masked).
-    returns   (num_edges, D).
+              pad lanes hold N.
+    returns   (num_edges, D) float32.
     """
     n, d = g.shape
-    e_pad = edge_dst.shape[0]
-    if e_pad % block_e != 0 or e_pad < num_edges:
-        raise ValueError(
-            f"edge_dst pad {e_pad} must be a block_e={block_e} multiple "
-            f"covering num_edges={num_edges}")
+    e_pad = _check_edge_dst(edge_dst, block_e, num_edges)
     if num_edges == 0:
-        return jnp.zeros((0, d), g.dtype)
-    bd = block_d or _pick_block_d(d, cap=128)
-    if d % bd != 0:
-        raise ValueError(f"feature dim {d} not divisible by block_d={bd}")
-    # the output is allocated at the true edge count: the final partial
-    # block is a masked boundary write (no (E_pad, d) intermediate, no
-    # slice, and — as every lane is independent — no pad copies of the
-    # operands either)
+        return jnp.zeros((0, d), jnp.float32)
+    gv = row_view(g)
+    dp = gv.shape[-1]
+    bd = block_d or _pick_block_d(dp)
+    if dp % bd != 0:
+        raise ValueError(f"feature dim {dp} not divisible by block_d={bd}")
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // bd, e_pad // block_e),
-        in_specs=[pl.BlockSpec((n, bd), lambda dt, c, dst: (0, dt))],
-        out_specs=pl.BlockSpec((block_e, bd), lambda dt, c, dst: (c, dt)),
+        num_scalar_prefetch=0,
+        grid=(dp // bd, e_pad // block_e),
+        in_specs=_edge_specs(block_e, lambda dt, c: (c, 0))
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((block_e, bd), lambda dt, c: (c, dt)),
+        scratch_shapes=[pltpu.SMEM((1, block_e), jnp.int32),
+                        pltpu.VMEM((block_e, bd), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        functools.partial(_gather_bwd_kernel, block_e=block_e),
+    out = pl.pallas_call(
+        functools.partial(_gather_bwd_kernel, num_segments=n, block_d=bd),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_edges, d), g.dtype),
+        out_shape=jax.ShapeDtypeStruct((num_edges, dp), jnp.float32),
         interpret=interpret,
-    )(edge_dst, g)
+    )(*_edge_args(edge_dst, block_e), gv)
+    return out[:, :d]
 
 
 # ---------------------------------------------------------------------------
@@ -126,15 +157,20 @@ def segment_sum_bwd_csc(g: jax.Array, edge_dst: jax.Array, num_edges: int,
 # ---------------------------------------------------------------------------
 
 
-def _gather_max_bwd_kernel(dst_ref, g_ref, fwd_ref, data_ref, out_ref, *,
-                           block_e: int):
+def _gather_max_bwd_kernel(dst_hbm, dst_col, g_hbm, fwd_hbm, data_ref,
+                           out_ref, dst_smem, gbuf, fbuf, sem, *,
+                           num_segments: int, block_d: int):
     """Gather backward masked by ``data == forward_max`` (subgradient:
     ties share the cotangent, matching ``jax.ops.segment_max``)."""
-    c = pl.program_id(1)
-    idx = dst_ref[pl.ds(c * block_e, block_e)]           # (BE,)
-    ge = jnp.take(g_ref[...], idx, axis=0, mode="clip")
-    fe = jnp.take(fwd_ref[...], idx, axis=0, mode="clip")
-    out_ref[...] = ge * (data_ref[...] == fe).astype(ge.dtype)
+    dt, c = pl.program_id(0), pl.program_id(1)
+    pltpu.sync_copy(dst_hbm.at[c], dst_smem)
+    live = _live(dst_smem, num_segments)
+    gather_rows(dst_smem, g_hbm, gbuf, sem, num_segments, dt * block_d,
+                block_d, live)
+    gather_rows(dst_smem, fwd_hbm, fbuf, sem, num_segments, dt * block_d,
+                block_d, live)
+    hit = (dst_col[...] < num_segments) & (data_ref[...] == fbuf[...])
+    out_ref[...] = jnp.where(hit, gbuf[...], 0.0)
 
 
 def segment_max_bwd_csc(g: jax.Array, fwd_out: jax.Array, data: jax.Array,
@@ -145,92 +181,113 @@ def segment_max_bwd_csc(g: jax.Array, fwd_out: jax.Array, data: jax.Array,
 
     g / fwd_out: (N, D) cotangent and saved forward output.
     data:        (E, D) the forward's edge operand (for the hit mask).
-    returns      (num_edges, D).
+    returns      (num_edges, D) float32.
     """
     n, d = g.shape
-    e_pad = edge_dst.shape[0]
     if fwd_out.shape != (n, d) or data.shape != (num_edges, d):
         raise ValueError(
             f"fwd_out {fwd_out.shape} / data {data.shape} do not match "
             f"the expected ({n}, {d}) / ({num_edges}, {d})")
-    if e_pad % block_e != 0 or e_pad < num_edges:
-        raise ValueError(
-            f"edge_dst pad {e_pad} must be a block_e={block_e} multiple "
-            f"covering num_edges={num_edges}")
+    e_pad = _check_edge_dst(edge_dst, block_e, num_edges)
     if num_edges == 0:
-        return jnp.zeros((0, d), g.dtype)
-    bd = block_d or _pick_block_d(d, cap=128)
-    if d % bd != 0:
-        raise ValueError(f"feature dim {d} not divisible by block_d={bd}")
-    # edge arrays stay at their true length: the final partial block is
-    # a boundary block (masked write, padded read) — no pad copy of the
-    # saved forward operand per backward call
+        return jnp.zeros((0, d), jnp.float32)
+    gv, fv, dv = row_view(g), row_view(fwd_out), lane_pad(data)
+    dp = gv.shape[-1]
+    bd = block_d or _pick_block_d(dp)
+    if dp % bd != 0:
+        raise ValueError(f"feature dim {dp} not divisible by block_d={bd}")
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(d // bd, e_pad // block_e),
-        in_specs=[
-            pl.BlockSpec((n, bd), lambda dt, c, dst: (0, dt)),
-            pl.BlockSpec((n, bd), lambda dt, c, dst: (0, dt)),
-            pl.BlockSpec((block_e, bd), lambda dt, c, dst: (c, dt)),
+        num_scalar_prefetch=0,
+        grid=(dp // bd, e_pad // block_e),
+        in_specs=_edge_specs(block_e, lambda dt, c: (c, 0)) + [
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((block_e, bd), lambda dt, c: (c, dt)),
         ],
-        out_specs=pl.BlockSpec((block_e, bd), lambda dt, c, dst: (c, dt)),
+        out_specs=pl.BlockSpec((block_e, bd), lambda dt, c: (c, dt)),
+        scratch_shapes=[pltpu.SMEM((1, block_e), jnp.int32),
+                        pltpu.VMEM((block_e, bd), jnp.float32),
+                        pltpu.VMEM((block_e, bd), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        functools.partial(_gather_max_bwd_kernel, block_e=block_e),
+    out = pl.pallas_call(
+        functools.partial(_gather_max_bwd_kernel, num_segments=n,
+                          block_d=bd),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_edges, d), g.dtype),
+        out_shape=jax.ShapeDtypeStruct((num_edges, dp), jnp.float32),
         interpret=interpret,
-    )(edge_dst, g, fwd_out, data)
+    )(*_edge_args(edge_dst, block_e), gv, fv, dv)
+    return out[:, :d]
 
 
 # ---------------------------------------------------------------------------
-# edge-softmax backward: recompute p_e in-kernel, one launch, heads on grid
+# edge-softmax backward: recompute p_e in-kernel, one launch, all heads
 # ---------------------------------------------------------------------------
 
 
-def _edge_softmax_bwd_kernel(dst_ref, logit_ref, val_ref, g_ref, m_ref,
-                             den_ref, og_ref, dlogit_ref, dval_ref, *,
-                             block_e: int):
-    """One (head, edge_chunk) grid step.
+def _edge_softmax_bwd_kernel(dst_hbm, dst_col, g_hbm, stat_hbm, logit_ref,
+                             val_ref, dlogit_ref, dval_ref, dst_smem, gbuf,
+                             sbuf, sem, *, num_segments: int, heads: int,
+                             head_dim: int):
+    """One edge-chunk grid step, all heads.
 
     With p_e = softmax_e(logit) over destination i's in-edges:
         d_value_e = p_e * g_i
         d_logit_e = p_e * (v_e . g_i  -  out_i . g_i)
     p_e is rebuilt here from the saved logits and the forward's softmax
     stats (running max m_i, denominator den_i) — never materialized as an
-    (E, H) tensor; ``og = out . g`` is the node-proportional contraction
-    precomputed by the wrapper.
+    (E, H) tensor. Each edge gathers its destination's cotangent row and
+    one packed stats row ``[m (H) | den (H) | out.g (H)]``.
     """
-    c = pl.program_id(1)
-    idx = dst_ref[pl.ds(c * block_e, block_e)]           # (BE,)
-    logit = logit_ref[:, 0]                              # (BE,)
-    m_e = jnp.take(m_ref[:, 0], idx, mode="clip")
-    den_e = jnp.take(den_ref[:, 0], idx, mode="clip")
-    # recompute-in-kernel; masked edges (logit == NEG) and pad lanes get
-    # p = 0 exactly, matching the reference math's masked exponentials
-    p = jnp.exp(logit - m_e) / jnp.maximum(den_e, 1e-20)
-    p = jnp.where(logit > NEG / 2, p, 0.0)
-    gi = jnp.take(g_ref[:, 0, :], idx, axis=0, mode="clip")   # (BE, D)
-    dval_ref[...] = (p[:, None] * gi)[:, None, :].astype(dval_ref.dtype)
-    vg = jnp.sum(val_ref[:, 0, :] * gi, axis=-1)              # (BE,)
-    oge = jnp.take(og_ref[:, 0], idx, mode="clip")
-    dlogit_ref[...] = (p * (vg - oge))[:, None].astype(dlogit_ref.dtype)
+    c = pl.program_id(0)
+    pltpu.sync_copy(dst_hbm.at[c], dst_smem)
+    live = _live(dst_smem, num_segments)
+    gather_rows(dst_smem, g_hbm, gbuf, sem, num_segments, 0, gbuf.shape[1],
+                live)
+    gather_rows(dst_smem, stat_hbm, sbuf, sem, num_segments, 0,
+                sbuf.shape[1], live)
+    valid = dst_col[...] < num_segments                        # (BE, 1)
+    # rows of lanes without a destination were never gathered
+    logits = logit_ref[...]
+    stats = jnp.where(valid, sbuf[...], 0.0)
+    g = jnp.where(valid, gbuf[...], 0.0)
+    vg_all = val_ref[...] * g
+    head_of_lane = jax.lax.broadcasted_iota(jnp.int32, (1, g.shape[1]),
+                                            1) // head_dim
+    logit_lane = jax.lax.broadcasted_iota(jnp.int32, (1, logits.shape[1]),
+                                          1)
+    dval = jnp.zeros_like(g)
+    dlogit = jnp.zeros_like(logits)
+    for h in range(heads):
+        logit = lane(logits, h)                                # (BE, 1)
+        # recompute-in-kernel; masked edges (logit == NEG) and pad lanes
+        # get p = 0 exactly, matching the reference math's masked
+        # exponentials
+        p = (jnp.exp(logit - lane(stats, h))
+             / jnp.maximum(lane(stats, heads + h), 1e-20))
+        p = jnp.where(valid & (logit > NEG / 2), p, 0.0)
+        mine = head_of_lane == h
+        dval = jnp.where(mine, p * g, dval)
+        vg = jnp.sum(jnp.where(mine, vg_all, 0.0), axis=1, keepdims=True)
+        dlogit = jnp.where(logit_lane == h,
+                           p * (vg - lane(stats, 2 * heads + h)), dlogit)
+    dval_ref[...] = dval
+    dlogit_ref[...] = dlogit
 
 
 def edge_softmax_bwd_csc(g: jax.Array, logits: jax.Array, values: jax.Array,
                          m: jax.Array, den: jax.Array, og: jax.Array,
                          edge_dst: jax.Array, num_edges: int,
                          block_e: int = 256, interpret: bool = False):
-    """Backward of the fused edge-softmax aggregation — one launch, heads
-    on the grid (mirroring the forward).
+    """Backward of the fused edge-softmax aggregation — one launch, all
+    heads per grid step (mirroring the forward).
 
     g (N, H, D) output cotangent; logits (E, H) / values (E, H, D) saved
     forward operands; m / den (N, H) the forward kernel's softmax stats;
-    og (N, H) = sum(out * g, -1). Returns (d_logits (E, H),
+    og (N, H) = sum(out * g, -1). Returns float32 (d_logits (E, H),
     d_values (E, H, D)).
     """
     n, h, d = g.shape
-    e_pad = edge_dst.shape[0]
     if logits.shape != (num_edges, h):
         raise ValueError(f"logits {logits.shape} do not match the "
                          f"expected ({num_edges}, {h})")
@@ -241,40 +298,42 @@ def edge_softmax_bwd_csc(g: jax.Array, logits: jax.Array, values: jax.Array,
         raise ValueError(
             f"softmax stats m {m.shape} / den {den.shape} / og {og.shape}"
             f" do not match the expected ({n}, {h})")
-    if e_pad % block_e != 0 or e_pad < num_edges:
-        raise ValueError(
-            f"edge_dst pad {e_pad} must be a block_e={block_e} multiple "
-            f"covering num_edges={num_edges}")
+    e_pad = _check_edge_dst(edge_dst, block_e, num_edges)
     if num_edges == 0:
-        return (jnp.zeros((0, h), logits.dtype),
-                jnp.zeros((0, h, d), values.dtype))
-    # saved edge operands stay at their true length — the final partial
-    # block is a boundary block, so no per-call pad copies of the (E, H)
-    # logits / (E, H, D) values residuals
+        return (jnp.zeros((0, h), jnp.float32),
+                jnp.zeros((0, h, d), jnp.float32))
+    gv = row_view(g.reshape(n, h * d))                          # (N, 1, Wp)
+    stats = row_view(jnp.concatenate([m, den, og], axis=1).astype(
+        jnp.float32))                                           # (N, 1, Sp)
+    lg = lane_pad(logits)                                       # (E, Hp)
+    vals = lane_pad(values.reshape(num_edges, h * d))           # (E, Wp)
+    hp, wp = lg.shape[1], vals.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        # head axis OUTERMOST (as in the forward): the per-head residents
-        # (cotangent block, stat columns) are fetched once per head
-        grid=(h, e_pad // block_e),
-        in_specs=[
-            pl.BlockSpec((block_e, 1), lambda hd, c, dst: (c, hd)),
-            pl.BlockSpec((block_e, 1, d), lambda hd, c, dst: (c, hd, 0)),
-            pl.BlockSpec((n, 1, d), lambda hd, c, dst: (0, hd, 0)),
-            pl.BlockSpec((n, 1), lambda hd, c, dst: (0, hd)),
-            pl.BlockSpec((n, 1), lambda hd, c, dst: (0, hd)),
-            pl.BlockSpec((n, 1), lambda hd, c, dst: (0, hd)),
+        num_scalar_prefetch=0,
+        grid=(e_pad // block_e,),
+        in_specs=_edge_specs(block_e, lambda c: (c, 0)) + [
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((block_e, hp), lambda c: (c, 0)),
+            pl.BlockSpec((block_e, wp), lambda c: (c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_e, 1), lambda hd, c, dst: (c, hd)),
-            pl.BlockSpec((block_e, 1, d), lambda hd, c, dst: (c, hd, 0)),
+            pl.BlockSpec((block_e, hp), lambda c: (c, 0)),
+            pl.BlockSpec((block_e, wp), lambda c: (c, 0)),
         ],
+        scratch_shapes=[pltpu.SMEM((1, block_e), jnp.int32),
+                        pltpu.VMEM((block_e, wp), jnp.float32),
+                        pltpu.VMEM((block_e, stats.shape[-1]), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        functools.partial(_edge_softmax_bwd_kernel, block_e=block_e),
+    d_logits, d_values = pl.pallas_call(
+        functools.partial(_edge_softmax_bwd_kernel, num_segments=n,
+                          heads=h, head_dim=d),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((num_edges, h), logits.dtype),
-            jax.ShapeDtypeStruct((num_edges, h, d), values.dtype),
+            jax.ShapeDtypeStruct((num_edges, hp), jnp.float32),
+            jax.ShapeDtypeStruct((num_edges, wp), jnp.float32),
         ],
         interpret=interpret,
-    )(edge_dst, logits, values, g, m, den, og)
+    )(*_edge_args(edge_dst, block_e), gv, stats, lg, vals)
+    return d_logits[:, :h], d_values[:, :h * d].reshape(num_edges, h, d)

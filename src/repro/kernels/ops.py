@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels (+ host-side planning).
 
-Each op takes ``interpret=`` so the TPU kernels validate on CPU; the pure
-jnp oracles live in ref.py. On this container everything runs in interpret
-mode; on a real TPU pod the same calls compile to Mosaic.
+Each op takes ``interpret=``; the default (``None``) resolves from the
+platform (:func:`default_interpret`): Mosaic on a TPU, the Pallas
+interpreter elsewhere, so the same calls validate on CPU. The pure jnp
+oracles live in ref.py. The Sum-stage kernels compute in float32; the
+wrappers cast their results back to the input dtype.
 """
 from __future__ import annotations
 
@@ -24,6 +26,15 @@ from repro.kernels.backward import (edge_softmax_bwd_csc,
 from repro.kernels.segment_sum import segment_sum_csc, segment_max_csc
 from repro.kernels.wkv6 import wkv6 as _wkv6_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode everywhere but on a TPU."""
+    return jax.default_backend() != "tpu"
+
+
+def _interp(interpret) -> bool:
+    return default_interpret() if interpret is None else bool(interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -181,7 +192,7 @@ def _reshape_to_2d(data):
 
 
 def segment_sum_op(data: jax.Array, plan: CSCPlan,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """data (E,)/(E, D)/(E, H, D) float -> (num_segments, ...trailing), via
     the Pallas CSC kernel (multi-head messages fold into the lane axis)."""
     if data.shape[0] != plan.num_edges:
@@ -190,12 +201,13 @@ def segment_sum_op(data: jax.Array, plan: CSCPlan,
     flat, trailing = _reshape_to_2d(data)
     out = _segment_reduce_planned(
         flat, jnp.asarray(plan.gather_idx), jnp.asarray(plan.local_ids),
-        plan.num_segments, plan.block_n, plan.block_e, interpret, "sum")
-    return out.reshape((plan.num_segments,) + trailing)
+        plan.num_segments, plan.block_n, plan.block_e, _interp(interpret),
+        "sum")
+    return out.reshape((plan.num_segments,) + trailing).astype(data.dtype)
 
 
 def segment_max_op(data: jax.Array, plan: CSCPlan,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Masked segment max; empty segments come back as NEG (callers clamp,
     matching the -inf identity of ``jax.ops.segment_max``)."""
     if data.shape[0] != plan.num_edges:
@@ -204,8 +216,9 @@ def segment_max_op(data: jax.Array, plan: CSCPlan,
     flat, trailing = _reshape_to_2d(data)
     out = _segment_reduce_planned(
         flat, jnp.asarray(plan.gather_idx), jnp.asarray(plan.local_ids),
-        plan.num_segments, plan.block_n, plan.block_e, interpret, "max")
-    return out.reshape((plan.num_segments,) + trailing)
+        plan.num_segments, plan.block_n, plan.block_e, _interp(interpret),
+        "max")
+    return out.reshape((plan.num_segments,) + trailing).astype(data.dtype)
 
 
 # -- fused backward wrappers (the custom_vjp bodies in core/aggregate) ------
@@ -220,7 +233,7 @@ def _segment_sum_bwd_planned(g, edge_dst, num_edges: int, block_e: int,
 
 
 def segment_sum_bwd_op(g: jax.Array, plan: CSCPlan,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """Backward of :func:`segment_sum_op`: g (num_segments, ...trailing)
     -> (E, ...trailing) via the plan-driven gather kernel (segment-sum is
     linear, so d_data[e] = g[dst[e]])."""
@@ -229,8 +242,9 @@ def segment_sum_bwd_op(g: jax.Array, plan: CSCPlan,
                          f"num_segments {plan.num_segments}")
     flat, trailing = _reshape_to_2d(g)
     out = _segment_sum_bwd_planned(flat, jnp.asarray(plan.edge_dst),
-                                   plan.num_edges, plan.block_e, interpret)
-    return out.reshape((plan.num_edges,) + trailing)
+                                   plan.num_edges, plan.block_e,
+                                   _interp(interpret))
+    return out.reshape((plan.num_edges,) + trailing).astype(g.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("num_edges", "block_e",
@@ -242,7 +256,8 @@ def _segment_max_bwd_planned(g, fwd_out, data, edge_dst, num_edges: int,
 
 
 def segment_max_bwd_op(g: jax.Array, fwd_out: jax.Array, data: jax.Array,
-                       plan: CSCPlan, interpret: bool = True) -> jax.Array:
+                       plan: CSCPlan,
+                       interpret: bool | None = None) -> jax.Array:
     """Backward of :func:`segment_max_op`: the gather kernel plus the
     in-kernel argmax-hit mask against the saved forward output."""
     if g.shape[0] != plan.num_segments:
@@ -255,8 +270,9 @@ def segment_max_bwd_op(g: jax.Array, fwd_out: jax.Array, data: jax.Array,
     ff, _ = _reshape_to_2d(fwd_out)
     df, _ = _reshape_to_2d(data)
     out = _segment_max_bwd_planned(gf, ff, df, jnp.asarray(plan.edge_dst),
-                                   plan.num_edges, plan.block_e, interpret)
-    return out.reshape((plan.num_edges,) + trailing)
+                                   plan.num_edges, plan.block_e,
+                                   _interp(interpret))
+    return out.reshape((plan.num_edges,) + trailing).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -312,7 +328,7 @@ def assert_sum_stage_fused(closed_jaxpr, plan: CSCPlan):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_op(r, k, v, w, u, chunk: int = 64, interpret: bool = True):
+def wkv6_op(r, k, v, w, u, chunk: int = 64, interpret: bool | None = None):
     """Chunked WKV6; pads T up to a chunk multiple and slices back."""
     B, T, H, K = r.shape
     pad = (-T) % chunk
@@ -321,7 +337,8 @@ def wkv6_op(r, k, v, w, u, chunk: int = 64, interpret: bool = True):
         r, k, v = zk(r), zk(k), zk(v)
         w = jnp.pad(w, ((0, 0), (0, pad), (0, 0), (0, 0)),
                     constant_values=1.0)
-    out = _wkv6_kernel(r, k, v, w, u, chunk=chunk, interpret=interpret)
+    out = _wkv6_kernel(r, k, v, w, u, chunk=chunk,
+                       interpret=_interp(interpret))
     return out[:, :T]
 
 
@@ -334,7 +351,7 @@ def wkv6_op(r, k, v, w, u, chunk: int = 64, interpret: bool = True):
     "causal", "sliding_window", "block_q", "block_k", "interpret"))
 def flash_attention_op(q, k, v, causal: bool = True, sliding_window: int = 0,
                        block_q: int = 128, block_k: int = 128,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """GQA-aware wrapper: repeats kv heads to q heads, pads T to blocks."""
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -362,7 +379,7 @@ def flash_attention_op(q, k, v, causal: bool = True, sliding_window: int = 0,
     out = _flash_kernel(q, k, v, causal=causal,
                         sliding_window=sliding_window,
                         block_q=bq, block_k=bk, seq_len=T,
-                        interpret=interpret)
+                        interpret=_interp(interpret))
     return out[:, :T]
 
 
@@ -378,7 +395,7 @@ def _edge_softmax_planned(logits, values, gather_idx, local_ids,
                           interpret: bool):
     from repro.kernels.edge_softmax import edge_softmax_csc
     # raw (E, H) / (E, H, D) operands go straight to the fused-gather
-    # kernel; heads run on the kernel grid in a single launch. The
+    # kernel; all heads run in a single launch. The
     # launch also yields the per-destination softmax stats (m, den) the
     # recompute-in-kernel backward rebuilds p_e from.
     out, m, den = edge_softmax_csc(logits, values, gather_idx, local_ids,
@@ -398,7 +415,7 @@ def _lift_single_head(logits, values):
 
 
 def edge_softmax_op(logits: jax.Array, values: jax.Array, plan: CSCPlan,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Fused GAT aggregation: softmax-weighted neighbor sums.
 
     Single-head: logits (E,), values (E, D) -> (num_segments, D).
@@ -412,7 +429,7 @@ def edge_softmax_op(logits: jax.Array, values: jax.Array, plan: CSCPlan,
 
 
 def edge_softmax_fwd_op(logits: jax.Array, values: jax.Array,
-                        plan: CSCPlan, interpret: bool = True):
+                        plan: CSCPlan, interpret: bool | None = None):
     """:func:`edge_softmax_op` plus the kernel's per-destination softmax
     stats: returns (out, m (num_segments, H), den (num_segments, H)) —
     the residuals the fused backward needs to rebuild p_e in-kernel."""
@@ -424,7 +441,8 @@ def edge_softmax_fwd_op(logits: jax.Array, values: jax.Array,
     lg, vals, single = _lift_single_head(logits, values)
     out, m, den = _edge_softmax_planned(
         lg, vals, g_idx, l_ids, plan.num_segments, plan.block_n,
-        plan.block_e, interpret)
+        plan.block_e, _interp(interpret))
+    out = out.astype(values.dtype)
     if single:
         return out[:, 0, :], m, den
     return out, m, den
@@ -441,13 +459,13 @@ def _edge_softmax_bwd_planned(g, logits, values, m, den, og, edge_dst,
 
 def edge_softmax_bwd_op(g: jax.Array, logits: jax.Array, values: jax.Array,
                         out: jax.Array, m: jax.Array, den: jax.Array,
-                        plan: CSCPlan, interpret: bool = True):
+                        plan: CSCPlan, interpret: bool | None = None):
     """Backward of :func:`edge_softmax_op` — the recompute-in-kernel pass.
 
     g / out (num_segments, H, D) cotangent and saved forward output;
     logits / values the saved forward operands; m / den the forward
     launch's softmax stats. Returns (d_logits, d_values) from one launch
-    with heads on the grid; the edge probabilities are rebuilt inside the
+    covering all heads; the edge probabilities are rebuilt inside the
     kernel (never an (E, H) tensor in HBM) and no reference segment pass
     runs.
     """
@@ -462,7 +480,9 @@ def edge_softmax_bwd_op(g: jax.Array, logits: jax.Array, values: jax.Array,
     og = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     d_logits, d_values = _edge_softmax_bwd_planned(
         g, lg, vals, m, den, og, jnp.asarray(plan.edge_dst),
-        plan.num_edges, plan.block_e, interpret)
+        plan.num_edges, plan.block_e, _interp(interpret))
+    d_logits = d_logits.astype(logits.dtype)
+    d_values = d_values.astype(values.dtype)
     if single:
         return d_logits[:, 0], d_values[:, 0, :]
     return d_logits, d_values

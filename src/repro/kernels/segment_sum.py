@@ -2,84 +2,94 @@
 
 The paper's stage breakdown (Fig. A3) shows graph convolution — dominated
 by the per-edge gather + per-destination aggregation — at 76% of runtime.
-On GPU this is a scatter-add; the TPU adaptation (DESIGN.md) reshapes it
-into MXU work: edges are sorted by destination (the CSC order GraphTheta
-already maintains, §4.1), destinations are tiled into blocks of ``BN``
-rows, each destination block owns a contiguous padded slice of edges, and
-the partial sum for a block is a **one-hot matmul**::
+On GPU this is a scatter-add; here edges are sorted by destination (the
+CSC order GraphTheta already maintains, §4.1), destinations are tiled
+into blocks of ``BN`` rows, and each destination block owns a contiguous
+padded slice of edges, processed in ``BE``-sized chunks by a sequential
+grid axis that revisits the same ``(BN, BD)`` output tile in VMEM. Within
+a chunk the kernel folds each gathered message row into its destination
+row, lane by lane in plan order::
 
-    out[BN, D] += onehot(local_dst)[BE, BN]^T @ messages[BE, D]
+    out[local_dst[l]] = op(out[local_dst[l]], messages[gather_idx[l]])
 
-which runs on the systolic array instead of a serialized scatter. The edge
-slice of a destination block is processed in ``BE``-sized chunks by a
-sequential grid axis revisiting the same output tile (accumulation in
-VMEM).
+with ``op`` = add (sum) or max. A destination's edges are folded in
+edge-id order whatever the block or chunk boundaries, so a node's
+aggregate is the same bits in any view that holds its in-edges — the
+full graph, a mini-batch, or a one-request serving view. (The one-hot
+matmul form, ``onehot[BN, BE] @ messages[BE, D]`` on the MXU, groups the
+additions by chunk and loses that property; it is left to a later
+measured change.)
 
 Fused gather
 ------------
 The per-edge gather happens **inside** the kernel: the raw ``(E, D)`` edge
-messages are the kernel operand and the plan's ``gather_idx`` rides in as a
-``PrefetchScalarGridSpec`` scalar-prefetch argument. Each grid step reads
-its ``BE`` indices and gathers the matching message rows on-chip — there is
-no ``(nb, L_pad, D)`` pre-gathered tensor in HBM anymore (that tensor
-duplicated every message byte and dominated Sum-stage memory traffic; see
+messages stay in HBM (``memory_space=pl.ANY``) and each grid step copies
+its chunk's ``BE`` plan indices into SMEM, then issues one row DMA per
+lane into a ``(BE, BD)`` VMEM scratch (:func:`gather_rows`). There is no
+``(nb, L_pad, D)`` pre-gathered tensor in HBM (that tensor duplicated
+every message byte and dominated Sum-stage memory traffic; see
 ``benchmarks/kernels_bench.py aggregate`` for the bytes-moved comparison).
-Padding lanes (``local_id == BN``) contribute nothing — the one-hot matmul
-and the masked max both null them — so no sentinel pad row is appended to
-the messages either; their (clipped) gather target is irrelevant.
+Padding lanes (``local_id == BN``) are never gathered nor folded: each
+step also copies the chunk's local ids to SMEM and issues DMAs only for
+live lanes (skewed plans are mostly padding).
+
+Mosaic layout rules shape the operands (every block's last two dims are
+(8, 128)-aligned or whole):
+
+- HBM operands that are gathered row by row are viewed as ``(rows, 1,
+  Dp)`` with ``Dp`` the feature width padded to a lane multiple (128): a
+  one-row DMA must not cut an (8, 128) tile, and the unit middle axis
+  gives each row a ``(1, 128)`` tiling. At ``D % 128 == 0`` the view is a
+  bitcast of the ``(E, D)`` array.
+- Plan index chunks are ``(nb * n_chunks, 1, BE)`` int32: one chunk is a
+  whole ``(1, BE)`` tile, copied to SMEM (indices drive DMA addresses and
+  the fold's row, and only scalars load from SMEM). Rows of VMEM refs
+  are addressed with dynamic sublane offsets.
+- Kernels compute in float32; the wrappers cast narrower inputs up and
+  the outputs back.
 
 Block geometry & VMEM budget
 ----------------------------
-Per grid step the kernel holds, in f32:
+Per grid step the forward kernels hold, in f32:
 
 =====================  =======================  =========================
 buffer                 shape                    bytes (defaults)
 =====================  =======================  =========================
-messages (resident)    (E, D)                   4·E·D   (fetched once; the
-                                                constant index map keeps
-                                                the block in VMEM across
-                                                grid steps)
-gather indices (SMEM)  (nb, L_pad) int32        4·nb·L_pad
-local ids              (1, BE)                  4·BE
-one-hot (sum)          (BE, BN)                 4·BE·BN      (256·128 → 128 KiB)
-candidates (max)       (BE, BN, BD)             4·BE·BN·BD   (256·128·64 → 8 MiB)
-output tile            (BN, D) / (BN, BD)       4·BN·D
+messages               (E, 1, Dp) in HBM        0 (never resident)
+plan chunk (SMEM)      (1, BE) int32            4·BE
+local ids (SMEM)       (1, BE) int32            4·BE
+gather scratch         (BE, BD)                 4·BE·BD (256·512 → 512 KiB)
+output tile            (BN, BD)                 4·BN·BD (128·512 → 256 KiB)
 =====================  =======================  =========================
 
-The max kernel's candidate expansion is the binding constraint: with the
-default ``block_e=256, block_n=128`` the feature tile ``BD`` is capped at
-**64** to stay within half of a ~16 MiB VMEM core; wider features are
-handled by the D-tiling grid axis (``_pick_block_d`` chooses the largest
-divisor of D within the cap), so D is no longer limited by VMEM. The
-message residency 4·E·D is the other budget line — for edge counts beyond
-VMEM on real hardware the messages move to ``pltpu.ANY``/HBM with
-per-chunk DMA (same kernel structure); interpret mode (this container)
-validates the arithmetic either way.
+``BD`` is ``_pick_block_d``: the whole padded width up to the cap (512
+lanes), else the largest multiple of 128 within the cap that divides it;
+the d-tile grid axis covers the rest. The edge softmax (edge_softmax.py)
+holds two ``(BE, H·D)`` gather buffers and three ``(BN, H·D)``
+accumulators. Nothing scales with E or N, so the kernels run at any
+graph size.
 
 Backward geometry (kernels in backward.py)
 ------------------------------------------
 The backward kernels run over the **edge axis** (grid ``(d_tiles,
-E_pad/BE)``; softmax: ``(H, E_pad/BE)``) with the node-indexed arrays
-resident, per grid step in f32:
+E_pad/BE)``; softmax: ``(E_pad/BE,)`` with heads unrolled in the body).
+The node-indexed arrays they read (cotangent ``g``, saved forward
+output, softmax stats) stay in HBM and are gathered per edge through the
+plan's inverse map ``edge_dst``, per grid step in f32:
 
 =====================  =======================  =========================
 buffer                 shape                    bytes (defaults)
 =====================  =======================  =========================
-edge_dst (SMEM)        (E_pad,) int32           4·E_pad
-cotangent g (resident) (N, BD) / (N, 1, D)      4·N·BD
-fwd out / stats        (N, BD) (max) or         4·N·BD / 3·4·N
-                       3×(N, 1) (softmax)
+edge_dst chunk (SMEM)  (1, BE) int32            4·BE
+edge_dst column        (BE, 1) int32 block      4·BE (lane-padded: 128 KiB)
+gathered rows          (BE, BD) per operand     4·BE·BD (max: 2 operands)
 edge tiles             (BE, BD) in + out        2·4·BE·BD
 =====================  =======================  =========================
 
-No ``(BE, BN, BD)`` candidate expansion exists in any backward kernel
-(the gather direction needs no one-hot), so the backward d-tile cap is
-looser (**128**) than the forward max kernel's 64; the binding line is
-the 4·N·D cotangent residency, which moves to HBM + per-chunk DMA at the
-same threshold as the forward's message residency. The softmax backward
-additionally keeps the per-edge probability entirely in registers/VMEM —
-it is rebuilt per tile from the saved logits and the forward-emitted
-(m, den) stats, never written to HBM.
+The softmax backward gathers the ``(N, H·D)`` cotangent rows and one
+packed row of per-destination stats ``[m | den | out·g]`` per edge, and
+rebuilds the edge probability in registers from the saved logits — it is
+never written to HBM.
 
 Host-side planning (``build_csc_plan`` in ops.py) computes the padded
 edge-slice layout once per graph — the paper's "reused CSR/CSC indexing"
@@ -87,19 +97,18 @@ edge-slice layout once per graph — the paper's "reused CSR/CSC indexing"
 
 The budget arithmetic above is not only documentation: the static
 analyzer in :mod:`repro.analysis.vmem` recomputes per-``pallas_call``
-block residency + peak temporary bytes from a traced jaxpr and flags any
-kernel whose footprint exceeds the budget (``vmem.budget`` rule; CLI
-``python -m repro.analysis --strict``). Changing a block geometry here
-without re-checking the tables trips that gate in CI.
+VMEM blocks + scratch buffers + peak temporary bytes from a traced jaxpr
+and flags any kernel whose footprint exceeds the budget (``vmem.budget``
+rule; CLI ``python -m repro.analysis --strict``). Changing a block
+geometry here without re-checking the tables trips that gate in CI.
 
 These kernels are wired into the forward paths through the Sum-stage
-backend registry in :mod:`repro.core.aggregate`: selecting the ``"csc"``
-:class:`~repro.core.aggregate.AggregationBackend` routes the combine of
-both ``layer_forward_block`` and the distributed engine through
-``segment_sum_csc`` / ``segment_max_csc`` / ``edge_softmax_csc`` (the
-``"reference"`` backend keeps the portable jnp segment ops). Multi-head
-``(E, H, D)`` messages fold into the lane axis for sum/max (ops.py
-wrappers); the edge-softmax kernel carries the head axis in its grid.
+backend registry in :mod:`repro.core.aggregate`: the ``"csc"``
+:class:`~repro.core.aggregate.AggregationBackend` (the default on TPU)
+routes the combine of both ``layer_forward_block`` and the distributed
+engine through ``segment_sum_csc`` / ``segment_max_csc`` /
+``edge_softmax_csc`` (the ``"reference"`` backend keeps the portable jnp
+segment ops). Multi-head ``(E, H, D)`` messages fold into the lane axis.
 
 ``NEG`` below is *the* masking sentinel of the repo — kernels, reference
 oracles, and attention masks all import it from here so empty-segment
@@ -115,50 +124,158 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+LANES = 128
 
 
-def _pick_block_d(d: int, cap: int = 64) -> int:
-    """Largest divisor of ``d`` within the VMEM cap (see module docstring).
+BLOCK_D_CAP = 512           # lanes per feature tile (VMEM budget above)
 
-    Falls back to 1 only for pathological prime widths; the common power-
-    of-two feature dims tile exactly.
-    """
-    if d <= cap:
+
+def _pick_block_d(d: int) -> int:
+    """Feature tile of the kernels (see module docstring): the whole
+    width when it fits the cap or is not a lane multiple, else the largest
+    multiple of 128 within the cap that divides it."""
+    if d <= BLOCK_D_CAP or d % LANES:
         return d
-    for bd in range(cap, 0, -1):
+    for bd in range(BLOCK_D_CAP, 0, -LANES):
         if d % bd == 0:
             return bd
-    return 1
+    return LANES
 
 
-def _segment_sum_kernel(idx_ref, ids_ref, msg_ref, out_ref, *,
-                        block_n: int, block_e: int):
-    """One (node_block, edge_chunk) grid step, gather fused in.
+def lane_pad(x: jax.Array) -> jax.Array:
+    """(R, D) -> (R, Dp) float32 with Dp the next multiple of 128."""
+    x = x.astype(jnp.float32)
+    pad = -x.shape[1] % LANES
+    return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
 
-    idx_ref: (nb, L_pad) int32 scalar-prefetch — rows of ``msg`` feeding
-             each lane (pad lanes point past E; clipped, then nulled by
-             the one-hot).
-    ids_ref: (1, BE) int32 — local destination row in [0, BN]; BN = pad.
-    msg_ref: (E, D) f32 — raw edge messages (constant block, VMEM
-             resident across the whole grid).
-    out_ref: (BN, D) f32 — destination tile (revisited across chunks).
+
+def row_view(x: jax.Array) -> jax.Array:
+    """(R, D) -> lane-padded (R, 1, Dp): the HBM layout that one-row DMAs
+    can address (module docstring)."""
+    x = lane_pad(x)
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
+def chunk_view(idx: jax.Array, block_e: int) -> jax.Array:
+    """Plan index array -> (n_chunks, 1, BE): one chunk per tile."""
+    return idx.reshape(-1, 1, block_e)
+
+
+def gather_rows(idx_smem, src_hbm, buf, sem, num_rows: int, d0, width: int,
+                live):
+    """``buf[i] = src_hbm[idx_smem[0, i], 0, d0:d0+width]`` for every lane
+    ``i`` with ``live(i)``: one row DMA per live lane, all in flight on one
+    semaphore, then drained. Rows of dead lanes (padding) keep whatever
+    the buffer held, so consumers mask them (or zero the buffer once)."""
+    def copy(i, row):
+        return pltpu.make_async_copy(
+            src_hbm.at[pl.ds(row, 1), 0, pl.ds(d0, width)],
+            buf.at[pl.ds(i, 1)], sem)
+
+    def start(i, carry):
+        @pl.when(live(i))
+        def _():
+            copy(i, jnp.minimum(idx_smem[0, i], num_rows - 1)).start()
+        return carry
+
+    def wait(i, carry):
+        @pl.when(live(i))
+        def _():
+            copy(i, 0).wait()
+        return carry
+
+    jax.lax.fori_loop(0, buf.shape[0], start, 0)
+    jax.lax.fori_loop(0, buf.shape[0], wait, 0)
+
+
+def _check_plan(gather_idx, num_blocks: int, block_e: int):
+    nb, l_pad = gather_idx.shape
+    if nb != num_blocks or l_pad % block_e != 0:
+        raise ValueError(
+            f"plan shape ({nb}, {l_pad}) inconsistent with "
+            f"num_blocks={num_blocks}, block_e={block_e}")
+    return l_pad // block_e
+
+
+def fold_rows(ids_smem, n_lanes: int, block_n: int, body):
+    """``body(i, r)`` for every lane ``i`` whose destination row ``r`` is
+    in the block, in lane order — i.e. each destination folds its edges
+    in plan (edge-id) order, whatever the chunking."""
+    def step(i, carry):
+        r = ids_smem[0, i]
+
+        @pl.when(r < block_n)
+        def _():
+            body(i, r)
+        return carry
+
+    jax.lax.fori_loop(0, n_lanes, step, 0)
+
+
+def _segment_fold_kernel(idx_hbm, ids_hbm, msg_hbm, out_ref, idx_smem,
+                         ids_smem, buf, sem, *, op, identity: float,
+                         block_n: int, n_chunks: int, num_edges: int,
+                         block_d: int):
+    """One (node_block, d_tile, edge_chunk) grid step, gather fused in.
+
+    idx_hbm: (nb*nc, 1, BE) int32 plan chunks in HBM — rows of ``msg``
+             feeding each lane.
+    ids_hbm: (nb*nc, 1, BE) int32 — local destination row of each lane in
+             [0, BN]; BN marks padding (never gathered, never folded).
+    msg_hbm: (E, 1, Dp) f32 raw edge messages in HBM.
+    out_ref: (BN, BD) f32 destination tile (revisited across chunks).
     """
-    b = pl.program_id(0)
-    chunk = pl.program_id(1)
+    b, dt, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(chunk == 0)
+    @pl.when(c == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[...] = jnp.full_like(out_ref, identity)
 
-    ids = ids_ref[0]                                    # (BE,)
-    idx = idx_ref[b, pl.ds(chunk * block_e, block_e)]   # (BE,)
-    data = jnp.take(msg_ref[...], idx, axis=0, mode="clip")  # fused gather
-    # one-hot on the MXU: (BE, BN) — padding lanes (id == BN) hit no row
-    onehot = (ids[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (ids.shape[0], block_n), 1)).astype(data.dtype)
-    out_ref[...] += jax.lax.dot_general(
-        onehot, data, (((0,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype)
+    chunk = b * n_chunks + c
+    pltpu.sync_copy(idx_hbm.at[chunk], idx_smem)
+    pltpu.sync_copy(ids_hbm.at[chunk], ids_smem)
+    gather_rows(idx_smem, msg_hbm, buf, sem, num_edges, dt * block_d,
+                block_d, lambda i: ids_smem[0, i] < block_n)
+
+    def fold(i, r):
+        out_ref[pl.ds(r, 1), :] = op(out_ref[pl.ds(r, 1), :],
+                                     buf[pl.ds(i, 1), :])
+
+    fold_rows(ids_smem, buf.shape[0], block_n, fold)
+
+
+def _segment_fold_csc(data, gather_idx, local_ids, num_blocks: int,
+                      block_n: int, block_e: int, block_d: int,
+                      interpret: bool, op, identity: float):
+    e, d = data.shape
+    nc = _check_plan(gather_idx, num_blocks, block_e)
+    if e == 0:
+        return jnp.full((num_blocks * block_n, d), identity, jnp.float32)
+    msg = row_view(data)
+    dp = msg.shape[-1]
+    bd = block_d or _pick_block_d(dp)
+    if dp % bd != 0:
+        raise ValueError(f"feature dim {dp} not divisible by block_d={bd}")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0,
+        grid=(num_blocks, dp // bd, nc),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=pl.BlockSpec((block_n, bd), lambda b, dt, c: (b, dt)),
+        scratch_shapes=[pltpu.SMEM((1, block_e), jnp.int32),
+                        pltpu.SMEM((1, block_e), jnp.int32),
+                        pltpu.VMEM((block_e, bd), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    out = pl.pallas_call(
+        functools.partial(_segment_fold_kernel, op=op, identity=identity,
+                          block_n=block_n, n_chunks=nc, num_edges=e,
+                          block_d=bd),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_blocks * block_n, dp),
+                                       jnp.float32),
+        interpret=interpret,
+    )(chunk_view(gather_idx, block_e), chunk_view(local_ids, block_e), msg)
+    return out[:, :d]
 
 
 def segment_sum_csc(data: jax.Array, gather_idx: jax.Array,
@@ -171,60 +288,10 @@ def segment_sum_csc(data: jax.Array, gather_idx: jax.Array,
                 (pad lanes hold E; L_pad % block_e == 0).
     local_ids:  (num_blocks, L_pad) int32 — destination row within block,
                 block_n for padding lanes.
-    returns     (num_blocks * block_n, D).
+    returns     (num_blocks * block_n, D) float32.
     """
-    e, d = data.shape
-    nb, l_pad = gather_idx.shape
-    if nb != num_blocks or l_pad % block_e != 0:
-        raise ValueError(
-            f"plan shape ({nb}, {l_pad}) inconsistent with "
-            f"num_blocks={num_blocks}, block_e={block_e}")
-    if e == 0:
-        return jnp.zeros((num_blocks * block_n, d), data.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_blocks, l_pad // block_e),
-        in_specs=[
-            pl.BlockSpec((1, block_e), lambda b, c, idx: (b, c)),
-            pl.BlockSpec((e, d), lambda b, c, idx: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, d), lambda b, c, idx: (b, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_segment_sum_kernel, block_n=block_n,
-                          block_e=block_e),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_blocks * block_n, d),
-                                       data.dtype),
-        interpret=interpret,
-    )(gather_idx, local_ids, data)
-
-
-def _segment_max_kernel(idx_ref, ids_ref, msg_ref, out_ref, *,
-                        block_n: int, block_e: int):
-    """Masked per-destination max over one (node_block, d_tile, edge_chunk)
-    step, gather fused in.
-
-    No one-hot matmul here — max has no MXU form — so the chunk expands to
-    a (BE, BN, BD) masked candidate tensor on the VPU; the d_tile grid axis
-    keeps BD within the VMEM cap (module docstring). Padding lanes
-    (id == BN) match no destination row and empty rows stay at NEG.
-    """
-    b = pl.program_id(1)
-    chunk = pl.program_id(2)
-
-    @pl.when(chunk == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, NEG)
-
-    ids = ids_ref[0]                                    # (BE,)
-    idx = idx_ref[b, pl.ds(chunk * block_e, block_e)]   # (BE,)
-    data = jnp.take(msg_ref[...], idx, axis=0, mode="clip")  # (BE, BD)
-    onehot = ids[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (ids.shape[0], block_n), 1)          # (BE, BN) bool
-    cand = jnp.where(onehot[:, :, None], data[:, None, :],
-                     jnp.asarray(NEG, data.dtype))      # (BE, BN, BD)
-    out_ref[...] = jnp.maximum(out_ref[...], jnp.max(cand, axis=0))
+    return _segment_fold_csc(data, gather_idx, local_ids, num_blocks,
+                             block_n, block_e, 0, interpret, jnp.add, 0.0)
 
 
 def segment_max_csc(data: jax.Array, gather_idx: jax.Array,
@@ -232,42 +299,9 @@ def segment_max_csc(data: jax.Array, gather_idx: jax.Array,
                     block_e: int = 256, block_d: int = 0,
                     interpret: bool = False):
     """Blocked segment-max; same fused-gather contract as
-    :func:`segment_sum_csc`, plus a feature-tiling grid axis.
-
-    ``block_d`` (0 = auto) tiles the feature axis so the (BE, BN, BD)
-    candidate tensor fits VMEM at any D — the auto pick is the largest
-    divisor of D within the documented cap. Empty destination rows come
-    back as ``NEG`` (callers clamp).
-    """
-    e, d = data.shape
-    nb, l_pad = gather_idx.shape
-    if nb != num_blocks or l_pad % block_e != 0:
-        raise ValueError(
-            f"plan shape ({nb}, {l_pad}) inconsistent with "
-            f"num_blocks={num_blocks}, block_e={block_e}")
-    if e == 0:
-        return jnp.full((num_blocks * block_n, d), NEG, data.dtype)
-    bd = block_d or _pick_block_d(d)
-    if d % bd != 0:
-        raise ValueError(f"feature dim {d} not divisible by block_d={bd}")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        # d-tiles OUTERMOST so the (E, BD) message block is fetched once
-        # per tile (its index map ignores b/c); chunks innermost so each
-        # (dt, b) output tile accumulates in VMEM
-        grid=(d // bd, num_blocks, l_pad // block_e),
-        in_specs=[
-            pl.BlockSpec((1, block_e), lambda dt, b, c, idx: (b, c)),
-            pl.BlockSpec((e, bd), lambda dt, b, c, idx: (0, dt)),
-        ],
-        out_specs=pl.BlockSpec((block_n, bd),
-                               lambda dt, b, c, idx: (b, dt)),
-    )
-    return pl.pallas_call(
-        functools.partial(_segment_max_kernel, block_n=block_n,
-                          block_e=block_e),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_blocks * block_n, d),
-                                       data.dtype),
-        interpret=interpret,
-    )(gather_idx, local_ids, data)
+    :func:`segment_sum_csc`. ``block_d`` forces the feature tile (0 =
+    :func:`_pick_block_d`). Empty destination rows come back as ``NEG``
+    (callers clamp)."""
+    return _segment_fold_csc(data, gather_idx, local_ids, num_blocks,
+                             block_n, block_e, block_d, interpret,
+                             jnp.maximum, NEG)

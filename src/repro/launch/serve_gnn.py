@@ -21,6 +21,7 @@ import time
 import numpy as np
 
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("serve_gnn")
 
@@ -121,6 +122,7 @@ def main(argv=None):
                          "(every request takes the K-hop path)")
     ap.add_argument("--staleness", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     job = api.TrainJob(dataset=args.dataset, model=args.model,
                        num_layers=args.layers, hidden=args.hidden,

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.config import GNNConfig, get_arch_config
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("train")
 
@@ -212,6 +213,7 @@ def main(argv=None):
     lm.add_argument("--reduced", action="store_true", default=True)
     lm.add_argument("--checkpoint-dir", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.cmd == "gnn":
         fault_policy = None

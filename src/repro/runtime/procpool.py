@@ -60,11 +60,13 @@ one-time warning (see :func:`warn_unavailable_once`).
 from __future__ import annotations
 
 import copy
+import io
 import os
 import pickle
 import queue as _queue
 import signal
 import struct
+import sys
 import threading
 import time
 import traceback
@@ -187,6 +189,34 @@ def _sampler_stream(stream):
     return s
 
 
+class _NoDeviceArrays(pickle.Pickler):
+    """Pickler for what ships to a sampler: a device array would make the
+    child initialise a JAX backend when it unpickles it (and contend for
+    the trainer's accelerator), so it is refused here, in the parent."""
+
+    def reducer_override(self, obj):
+        import jax
+        if isinstance(obj, jax.Array):
+            raise TypeError(
+                "a sampler stream must hold host (numpy) data only; found "
+                f"a device array of shape {obj.shape}")
+        return NotImplemented
+
+
+def _ship(stream) -> bytes:
+    buf = io.BytesIO()
+    _NoDeviceArrays(buf, protocol=pickle.HIGHEST_PROTOCOL).dump(stream)
+    return buf.getvalue()
+
+
+def _jax_backend_live() -> bool:
+    """Whether this process has initialised a JAX backend."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
 def _slot_bytes_for(stream) -> int:
     """A capacity bound covering any view the stream can emit (dense
     mask views and compact relabeled views alike), plus headroom for
@@ -235,14 +265,22 @@ def _mute_child_shm_tracking() -> None:
         pass  # lint: waive=src.silent-except
 
 
-def _worker_main(wid: int, start: int, stream, shm_name: str,
+def _worker_main(wid: int, start: int, stream_bytes: bytes, shm_name: str,
                  hb_name: str, nworkers: int, slot_bytes: int,
                  task_q, done_q, inj_spec) -> None:
     """One sampler process: claim tasks from ``task_q``, build views
     (pure in ``(seed, i)``), write them into shared-memory slots, report
-    on ``done_q``. Heartbeats via the shared ``hb`` array."""
+    on ``done_q``. Heartbeats via the shared ``hb`` array.
+
+    The accelerator belongs to the trainer process. A sampler builds
+    views with numpy only and must never initialise a JAX backend: JAX is
+    pinned to the CPU here before the stream is unpickled, and a build
+    that brought a backend up is reported as a failed build."""
     # ctrl-C belongs to the trainer: the parent's close() retires us
     signal.signal(signal.SIGINT, signal.SIG_IGN)
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    stream = pickle.loads(stream_bytes)
     _mute_child_shm_tracking()
     seg = _shm.SharedMemory(name=shm_name)
     hbseg = _shm.SharedMemory(name=hb_name)
@@ -271,6 +309,11 @@ def _worker_main(wid: int, start: int, stream, shm_name: str,
                     os.kill(os.getpid(), signal.SIGKILL)
             try:
                 view = stream.build(start + i, builder)
+                if _jax_backend_live():
+                    raise RuntimeError(
+                        "sampler process initialised a JAX backend while "
+                        f"building view {i}: view builds must stay on "
+                        "the host (numpy)")
                 payload = pickle.dumps(
                     _strip_view(view), protocol=pickle.HIGHEST_PROTOCOL)
                 if len(payload) > slot_bytes - _PAYLOAD_OFF:
@@ -371,6 +414,9 @@ class ProcessViewService:
         self._claims: dict = {}         # wid -> (i, slot, t_assigned)
         self._free = list(range(self._nslots))
 
+        # what ships to every sampler: caches pruned, builder detached,
+        # host data only (refused here, before any resource exists)
+        self._child_stream = _ship(_sampler_stream(stream))
         try:
             self._ctx = multiprocessing.get_context("spawn")
         except ValueError as e:
@@ -381,8 +427,6 @@ class ProcessViewService:
         self._hb = np.ndarray((workers,), np.float64,
                               buffer=self._hbseg.buf)
         self._hb[:] = time.monotonic()
-        # what ships to every sampler: caches pruned, builder detached
-        self._child_stream = _sampler_stream(stream)
         inj = runtime.injector if runtime is not None else None
         self._inj = inj
         self._inj_spec = None

@@ -37,6 +37,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from repro.core.aggregate import backend_name
 from repro.core.tgar import layer_forward_block
 from repro.core.trainer import BucketedFn
 from repro.core.views import BucketSpec, CompactBlockBuilder, ViewBuilder
@@ -150,7 +151,7 @@ class GNNServer:
         # without limit
         self.max_queue = (8 * self.max_batch if max_queue is None
                           else max(1, int(max_queue)))
-        backend = getattr(model, "aggregate_backend", "reference")
+        backend = backend_name(model)
         csc = backend == "csc"
         self.buckets = buckets or BucketSpec.for_graph(g)
         self._builder = ViewBuilder(g, model.K, compact=True)

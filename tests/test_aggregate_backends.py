@@ -11,7 +11,8 @@ import pytest
 
 from conftest import run_with_devices
 from repro.config import GNNConfig
-from repro.core.aggregate import COMBINE_SPECS, combine, get_backend
+from repro.core.aggregate import (COMBINE_SPECS, MissingPlanError,
+                                  backend_name, combine, get_backend)
 from repro.core.mpgnn import loss_block
 from repro.core.strategies import global_batch_view, mini_batch_views
 from repro.graph import sbm_graph
@@ -90,6 +91,28 @@ def test_unknown_mode_and_backend_raise():
         combine("median", msg, dst, 90, mask)
     with pytest.raises(ValueError, match="backend"):
         get_backend("cuda")
+
+
+def test_csc_without_plan_raises_typed_error():
+    """The kernel backend never degrades to the reference ops in silence:
+    a combine without a CSCPlan is a typed error, in every mode."""
+    msg, dst, ids_np, mask = _edge_problem(seed=2, H=1, D=4)
+    for mode in MODES:
+        with pytest.raises(MissingPlanError, match="CSCPlan"):
+            combine(mode, msg, dst, 90, mask, backend="csc")
+
+
+def test_backend_default_follows_platform(monkeypatch):
+    """No configured name: the kernels on TPU, the reference elsewhere;
+    an explicit name is honoured on any platform."""
+    cfg = GNNConfig(model="gcn", num_classes=3, feature_dim=8)
+    here = "csc" if jax.default_backend() == "tpu" else "reference"
+    assert cfg.aggregate_backend is None
+    assert backend_name(make_gnn(cfg)) == get_backend(None).name == here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert backend_name(make_gnn(cfg)) == get_backend(None).name == "csc"
+    pinned = dataclasses.replace(cfg, aggregate_backend="reference")
+    assert backend_name(make_gnn(pinned)) == "reference"
 
 
 @pytest.mark.parametrize("model_name,heads",

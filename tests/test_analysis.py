@@ -98,7 +98,7 @@ def test_full_graph_aval_fixture_flagged():
 
 
 def test_f64_fixture_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(lambda x: x * np.float64(2.0))(
             jnp.ones(4, jnp.float64))
     findings = run_rules(JaxprContext(jx), ids=["jaxpr.f64-promotion"])
@@ -136,17 +136,19 @@ def test_donation_fixture_flagged():
 
 def test_vmem_budget_fixture_flagged():
     """segment_max_csc at the documented block geometry with an unsplit
-    feature axis (block_d == d == 256) materializes a (BE, BN, BD) =
-    (256, 128, 256) candidate tensor — 32 MiB, over the 16 MiB budget;
-    the auto-tiled pick stays under it."""
+    feature axis (block_d == d == 16384) needs a (BE, BD) = (256, 16384)
+    gather scratch — 16 MiB on its own, plus the (BN, BD) output tile and
+    the loaded rows — over the 16 MiB budget; the auto-tiled pick (512
+    lanes) stays under it. The messages themselves stay in HBM and count
+    nothing."""
     from repro.kernels.segment_sum import segment_max_csc
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 40, 96).astype(np.int32)
     plan = build_csc_plan(ids, 40, block_n=128, block_e=256)
-    data = jnp.ones((plan.num_edges, 256), jnp.float32)
+    data = jnp.ones((plan.num_edges, 16384), jnp.float32)
     jx = jax.make_jaxpr(lambda d: segment_max_csc(
         d, jnp.asarray(plan.gather_idx), jnp.asarray(plan.local_ids),
-        plan.num_blocks, plan.block_n, plan.block_e, block_d=256,
+        plan.num_blocks, plan.block_n, plan.block_e, block_d=16384,
         interpret=True))(data)
     findings = check_vmem(jx)
     assert _rule_ids(findings) == {"vmem.budget"}
@@ -160,6 +162,12 @@ def test_vmem_budget_fixture_flagged():
     # stats reconstruction is sane: every launch reports a grid and bytes
     stats = iter_kernel_stats(jx)
     assert stats and all(s.vmem_bytes > 0 and s.grid for s in stats)
+    st = stats[0]
+    assert st.scratch_bytes >= 256 * 16384 * 4        # the gather buffer
+    # the HBM-resident messages are walked but not priced as VMEM
+    assert any(b["memory_space"] == "any" for b in st.blocks)
+    assert st.block_bytes == sum(b["bytes"] for b in st.blocks
+                                 if b["memory_space"] == "vmem")
 
 
 def test_srclint_bare_assert_fixture_flagged():

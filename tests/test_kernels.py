@@ -248,14 +248,17 @@ def test_edge_softmax_multi_head_single_launch():
 
 def test_segment_max_d_tiled_wide_features():
     """D > the VMEM cap exercises the d-tile grid axis of the fused max
-    kernel (the (BE, BN, BD) candidate tensor stays bounded)."""
+    kernel (the (BE, BD) gather scratch stays bounded). Tiles are whole
+    widths or lane multiples, as Mosaic's (8, 128) block rule needs."""
     from repro.kernels.ops import segment_max_op
     from repro.kernels.segment_sum import _pick_block_d
-    assert _pick_block_d(48) == 48
-    assert _pick_block_d(160) == 40            # largest divisor <= 64
-    assert _pick_block_d(128) == 64
+    assert _pick_block_d(48) == 48             # under the cap: whole
+    assert _pick_block_d(160) == 160
+    assert _pick_block_d(1024) == 512          # largest lane multiple
+    assert _pick_block_d(1152) == 384          # dividing D within 512
+    assert _pick_block_d(640) == 128
     rng = np.random.default_rng(13)
-    E, N, D = 700, 90, 160
+    E, N, D = 700, 90, 1100                    # lane-padded to 1152
     ids = rng.integers(0, N, E).astype(np.int32)
     data = jnp.asarray(rng.normal(size=(E, D)), jnp.float32)
     plan = build_csc_plan(ids, N, block_n=32, block_e=64)

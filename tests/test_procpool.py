@@ -14,12 +14,14 @@ import subprocess
 import sys
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.config import GNNConfig
 from repro.core.strategies import strategy_views
 from repro.core.trainer import CompactTrainer
+from repro.core.views import MiniBatchViewStream
 from repro.graph import sbm_graph
 from repro.models import make_gnn
 from repro.optim import adam
@@ -112,6 +114,41 @@ def test_service_cursor_tracks_emission(g):
         assert stream.cursor == 1   # cursor counts *emitted* views only
         next(it)
         assert stream.cursor == 2
+    finally:
+        it.close()
+    assert _no_children()
+
+
+class _DeviceTouchingStream(MiniBatchViewStream):
+    """A stream whose view build brings a JAX backend up — what a sampler
+    process must never do while the trainer holds the accelerator."""
+
+    def build(self, i, builder=None):
+        jnp.zeros(1).block_until_ready()
+        return super().build(i, builder)
+
+
+def test_sampler_refuses_device_arrays(g):
+    """A device array in the shipped stream would initialise a backend
+    in the child as it is unpickled: the parent refuses to ship it."""
+    stream = _views(g)
+    stream.stray = jnp.ones(3)
+    with pytest.raises(TypeError, match="device array"):
+        ProcessViewService(stream, lambda v: v, 2, workers=1)
+    assert _no_children()
+
+
+def test_sampler_never_initialises_jax_backend(g):
+    """Every sampler build is checked: one that initialised a JAX
+    backend fails as a typed build error instead of contending for the
+    trainer's chip. Plain streams pass the same check (every other test
+    in this file)."""
+    stream = _views(g)
+    stream.__class__ = _DeviceTouchingStream
+    it = ProcessViewService(stream, lambda v: v, 2, workers=1)
+    try:
+        with pytest.raises(RuntimeError, match="initialised a JAX backend"):
+            list(it)
     finally:
         it.close()
     assert _no_children()

@@ -100,6 +100,23 @@ def test_assert_compiled_once_raises(setup):
         trainer.assert_compiled_once()
 
 
+def test_compiled_once_across_reset_and_restore(setup, tmp_path):
+    """Fresh state from reset() and checkpointed state from restore()
+    enter the step with the same types as the step's own outputs, so
+    neither retraces."""
+    g, engine, clusters = setup
+    trainer = Trainer(engine, adam(1e-2), seed=0)
+    trainer.fit(_views(g, "global", clusters), steps=2)
+    trainer.save(str(tmp_path))
+    trainer.reset(seed=1)
+    trainer.fit(_views(g, "mini", clusters), steps=2)
+    trainer.restore(str(tmp_path))
+    trainer.fit(_views(g, "cluster", clusters), steps=2)
+    assert trainer.step_num == 4
+    assert trainer.trace_counts["train_step"] == 1
+    trainer.assert_compiled_once()
+
+
 # ---------------------------------------------------------------------------
 # prefetch pipeline
 # ---------------------------------------------------------------------------
