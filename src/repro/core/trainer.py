@@ -77,12 +77,15 @@ from repro.runtime.prefetch import StreamPrefetcher, ViewPrefetcher
 from repro.runtime.procpool import (ProcessViewService,
                                     ProcPoolUnavailable,
                                     warn_unavailable_once)
+from repro.utils.timing import annotate, span
 
 # the pipelines moved to repro.runtime.prefetch (where supervision
 # lives); these aliases keep the long-standing private import paths of
 # tests/benches working
 _ViewPrefetcher = ViewPrefetcher
 _MultiStreamPrefetcher = StreamPrefetcher
+
+_END = object()      # the staged-view iterator ran out
 
 
 class RetraceError(AssertionError):
@@ -317,7 +320,22 @@ class BaseTrainer:
         so at most that many steps' view/activation buffers are live at
         once — deep run-ahead piles up device memory and (on CPU) slows
         the executor more than the overlap buys.
+
+        The call is one ``train.fit`` span, whose ``steps`` counts the
+        steps it returns losses for.
         """
+        with span("train.fit"):
+            out = self._fit(views, steps, prefetch, prefetch_workers,
+                            prefetch_mode, eval_every, eval_view, eval_mask,
+                            checkpoint_every, checkpoint_dir, max_in_flight,
+                            log_every, log, resume)
+            annotate(steps=len(out["losses"]))
+            return out
+
+    def _fit(self, views, steps, prefetch, prefetch_workers, prefetch_mode,
+             eval_every, eval_view, eval_mask, checkpoint_every,
+             checkpoint_dir, max_in_flight, log_every, log,
+             resume) -> dict:
         rt = self.runtime
         if resume and checkpoint_dir:
             from repro.checkpoint import latest_step
@@ -390,28 +408,39 @@ class BaseTrainer:
         try:
             # idx counts views consumed THIS fit — monotonic even across
             # a rollback (which rewinds step_num), so a keyed "diverge"
-            # injection fires exactly once per poison view
-            for idx, staged in enumerate(staged_iter):
+            # injection fires exactly once per poison view. Every
+            # pipeline yields at most ``steps`` views, so the loop stops
+            # there without a last wait for the end of the stream
+            staged_views = iter(staged_iter)
+            for idx in itertools.count():
+                if steps is not None and idx >= steps:
+                    break
+                with span("train.view_wait", view=idx):
+                    staged = next(staged_views, _END)
+                if staged is _END:
+                    break
                 if max_in_flight > 0 and len(pending) >= max_in_flight:
                     # backpressure: wait on the oldest in-flight step (one
                     # scalar readiness wait, not a pipeline-wide sync) and
                     # retire its loss to a host float so live device
                     # arrays stay O(max_in_flight), not O(steps)
-                    losses.append(float(pending.pop(0)))
+                    with span("train.backpressure"):
+                        losses.append(float(pending.pop(0)))
                 # pre-step refs: jax arrays are immutable, so holding the
                 # old (params, opt_state) costs nothing and is the whole
                 # skip_view recovery
                 prev = (self.params, self.opt_state, self.step_num)
-                if rt is None:
-                    self.params, self.opt_state, loss = \
-                        self._dispatch(staged)
-                else:
-                    # step dispatch is a retryable stage too: a transient
-                    # failure re-dispatches the same (params, staged) —
-                    # deterministic by construction
-                    self.params, self.opt_state, loss = rt(
-                        "step", lambda: self._dispatch(staged),
-                        key=self.step_num)
+                with span("train.dispatch"):
+                    if rt is None:
+                        self.params, self.opt_state, loss = \
+                            self._dispatch(staged)
+                    else:
+                        # step dispatch is a retryable stage too: a
+                        # transient failure re-dispatches the same
+                        # (params, staged) — deterministic by construction
+                        self.params, self.opt_state, loss = rt(
+                            "step", lambda: self._dispatch(staged),
+                            key=self.step_num)
                 self.step_num += 1
                 self.view_cursor = (stream.cursor if stream is not None
                                     else self.step_num)

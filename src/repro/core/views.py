@@ -45,6 +45,7 @@ from typing import Iterator, Optional, Sequence
 import numpy as np
 
 from repro.graph.csr import Graph, GraphBlock, base_block
+from repro.utils.timing import annotate, recording, span
 from repro.core.subgraph import (bfs_layers, bfs_layers_fresh,
                                  fill_khop_masks, stamped_in_edges)
 
@@ -403,6 +404,22 @@ class CompactBlockBuilder:
         return self._pick(view)
 
     def stage(self, view) -> GraphBlock:
+        """The view's padded block (with its CSCPlan when ``csc_plan``),
+        under a ``view.stage`` span whose counters, while a profiler
+        session records, give the plan's lanes (what one Sum-stage pass
+        walks) and the view's live edges."""
+        with span("view.stage"):
+            block = self._stage(view)
+            if recording():
+                plan = block.csc_plan
+                annotate(plan_lanes=(0 if plan is None
+                                     else int(plan.gather_idx.size)),
+                         live_edges=(view.graph.num_edges
+                                     if isinstance(view, GraphView)
+                                     else view.num_edges))
+            return block
+
+    def _stage(self, view) -> GraphBlock:
         self.stages += 1
         if isinstance(view, GraphView):
             return view.as_block(gcn_norm=self.gcn_norm,

@@ -43,6 +43,7 @@ from typing import Iterable, Iterator, Optional
 
 from repro.runtime.faults import (PrefetchShutdownError, Retrier,
                                   WorkerKilled)
+from repro.utils.timing import span
 
 
 class ViewPrefetcher:
@@ -85,8 +86,14 @@ class ViewPrefetcher:
 
     def _run(self, views, prepare):
         try:
-            for v in views:
-                if self._cancel.is_set() or not self._put(prepare(v)):
+            # a plain iterator hands over views already built, so the
+            # build span covers their staging
+            for i, v in enumerate(views):
+                if self._cancel.is_set():
+                    return
+                with span("prefetch.build", view=i):
+                    item = prepare(v)
+                if not self._put(item):
                     return
         except BaseException as e:  # noqa: BLE001 — surfaced in __next__
             self._err = e
@@ -246,9 +253,9 @@ class StreamPrefetcher:
 
     def _build_one(self, i: int, builder):
         def build():
-            item = self._prepare(
-                self._stream.build(self._start + i, builder))
-            return item
+            with span("view.sample"):
+                view = self._stream.build(self._start + i, builder)
+            return self._prepare(view)
 
         rt = self._runtime
         if rt is None:
@@ -294,7 +301,8 @@ class StreamPrefetcher:
                     return
                 i, cid = claim
                 try:
-                    item = self._build_one(i, builder)
+                    with span("prefetch.build", view=i):
+                        item = self._build_one(i, builder)
                 except WorkerKilled:
                     with self._cond:
                         if self._claims.get(i, (None,))[0] == cid:

@@ -29,6 +29,9 @@ same CSC order, so the aggregation sums bitwise-identically.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import itertools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -43,6 +46,9 @@ from repro.core.trainer import BucketedFn
 from repro.core.views import BucketSpec, CompactBlockBuilder, ViewBuilder
 from repro.graph.csr import Graph
 from repro.serving.cache import EmbeddingCache
+from repro.utils.timing import annotate, recording, span
+
+LATENCY_WINDOW = 65536   # requests whose latency ServeStats keeps
 
 
 class ServerClosedError(RuntimeError):
@@ -58,14 +64,19 @@ class ServerOverloadedError(RuntimeError):
 @dataclass
 class ServeStats:
     """Per-stage timing + cache/batching counters; ``summary()`` folds in
-    latency percentiles and trace certificates."""
+    latency percentiles and trace certificates. The stage times are the
+    durations of the server's ``serve.*`` spans (``repro.utils.timing``),
+    read from the spans themselves; ``latencies_s`` keeps the latest
+    ``LATENCY_WINDOW`` requests, so its percentiles describe recent
+    traffic."""
     requests: int = 0
     batches: int = 0
     queue_wait_s: float = 0.0
     view_build_s: float = 0.0
     device_step_s: float = 0.0
     gather_s: float = 0.0
-    latencies_s: list = field(default_factory=list)
+    latencies_s: collections.deque = field(
+        default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
 
     def record_batch(self, n: int, queue_wait: float = 0.0) -> None:
         """Count one served batch (stage times accumulate separately as
@@ -178,6 +189,7 @@ class GNNServer:
         # one batch in flight at a time: staging mutates per-bucket ring
         # buffers and the cache write-back must be ordered
         self._serve_lock = threading.Lock()
+        self._batch_ids = itertools.count()
 
         K = model.K
 
@@ -211,33 +223,36 @@ class GNNServer:
 
     # -- the device paths ------------------------------------------------------
 
+    def _build(self, builder: ViewBuilder, stager: CompactBlockBuilder,
+               targets: np.ndarray):
+        """The targets' compact view and its detached padded block
+        (``serve.view`` then ``serve.stage``; together, view build)."""
+        with span("serve.view") as built:
+            view = builder.khop_compact(targets)
+        with span("serve.stage") as staged:
+            block = jax.tree_util.tree_map(np.array, stager.stage(view))
+        self.stats.view_build_s += (staged.end_ns - built.start_ns) / 1e9
+        return view, block
+
     def _infer_full(self, targets: np.ndarray) -> np.ndarray:
         """K-hop path for (sorted unique) targets; writes back h^{K-1}."""
-        t0 = time.perf_counter()
-        view = self._builder.khop_compact(targets)
-        block = jax.tree_util.tree_map(np.array, self._stager.stage(view))
-        t1 = time.perf_counter()
-        logits, penult = self._full_step(self.params, block)
-        logits = np.asarray(logits)
-        t2 = time.perf_counter()
+        view, block = self._build(self._builder, self._stager, targets)
+        with span("serve.device", path="full") as dev:
+            logits, penult = self._full_step(self.params, block)
+            logits = np.asarray(logits)
+        self.stats.device_step_s += dev.seconds
         if self.cache is not None:
-            m = int(view.hop_offsets[1])     # nodes within 1 hop: a prefix
-            self.cache.put(view.nodes[:m], np.asarray(penult)[:m])
-        self.stats.view_build_s += t1 - t0
-        self.stats.device_step_s += t2 - t1
+            with span("serve.writeback"):
+                m = int(view.hop_offsets[1])  # nodes within 1 hop: a prefix
+                self.cache.put(view.nodes[:m], np.asarray(penult)[:m])
         return logits[:len(targets)]
 
     def _infer_hit(self, targets: np.ndarray) -> np.ndarray:
         """1-hop top-layer path over cached h^{K-1} rows."""
-        t0 = time.perf_counter()
-        view = self._hit_builder.khop_compact(targets)
-        block = jax.tree_util.tree_map(np.array,
-                                       self._hit_stager.stage(view))
-        t1 = time.perf_counter()
-        logits = np.asarray(self._hit_step(self.params, block))
-        t2 = time.perf_counter()
-        self.stats.view_build_s += t1 - t0
-        self.stats.device_step_s += t2 - t1
+        _, block = self._build(self._hit_builder, self._hit_stager, targets)
+        with span("serve.device", path="hit") as dev:
+            logits = np.asarray(self._hit_step(self.params, block))
+        self.stats.device_step_s += dev.seconds
         return logits[:len(targets)]
 
     def submit(self, node_ids: Sequence[int]) -> np.ndarray:
@@ -253,22 +268,37 @@ class GNNServer:
         if nodes.min() < 0 or nodes.max() >= self.g.num_nodes:
             raise ValueError(
                 f"node ids must lie in [0, {self.g.num_nodes})")
-        t0 = time.perf_counter()
-        with self._serve_lock:
-            out = self._serve_locked(nodes)
-        lat = time.perf_counter() - t0
-        self.stats.latencies_s.extend([lat] * len(nodes))
+        with span("serve.batch", batch=next(self._batch_ids),
+                  requests=len(nodes)) as served:
+            with self._locked():
+                out = self._serve_locked(nodes)
+        self.stats.latencies_s.extend([served.seconds] * len(nodes))
         self.stats.record_batch(len(nodes))
         return out
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """Hold the serve lock; the wait for it is ``serve.lock``."""
+        with span("serve.lock"):
+            self._serve_lock.acquire()
+        try:
+            yield
+        finally:
+            self._serve_lock.release()
+
     def _serve_locked(self, nodes: np.ndarray) -> np.ndarray:
-        targets = np.unique(nodes)           # sorted — hop-0 view order
-        if self.cache is not None:
-            hit_mask = self.cache.coverage(targets)
-            self.cache.hits += int(hit_mask.sum())
-            self.cache.misses += int((~hit_mask).sum())
-        else:
-            hit_mask = np.zeros(len(targets), bool)
+        with span("serve.cover"):
+            targets = np.unique(nodes)       # sorted — hop-0 view order
+            if self.cache is not None:
+                hit_mask = self.cache.coverage(targets)
+                hits = int(hit_mask.sum())
+                self.cache.hits += hits
+                self.cache.misses += len(targets) - hits
+            else:
+                hit_mask = np.zeros(len(targets), bool)
+                hits = 0
+        if recording():      # on the enclosing serve.batch
+            annotate(misses=len(targets) - hits)
         out = np.empty((len(targets), self.model.num_classes), np.float32)
         miss = targets[~hit_mask]
         if len(miss):
@@ -276,10 +306,10 @@ class GNNServer:
         hit = targets[hit_mask]
         if len(hit):
             out[hit_mask] = self._infer_hit(hit)
-        t0 = time.perf_counter()
-        rows = np.searchsorted(targets, nodes)
-        result = out[rows]
-        self.stats.gather_s += time.perf_counter() - t0
+        with span("serve.gather") as gather:
+            rows = np.searchsorted(targets, nodes)
+            result = out[rows]
+        self.stats.gather_s += gather.seconds
         return result
 
     # -- the batching queue (concurrent clients) -------------------------------
@@ -360,7 +390,7 @@ class GNNServer:
 
     def _dispatch_loop(self) -> None:
         while True:
-            with self._cv:
+            with span("serve.collect"), self._cv:
                 while self._running and not self._queue:
                     self._cv.wait(0.1)
                 if not self._running and not self._queue:
@@ -379,23 +409,26 @@ class GNNServer:
             self._serve_pending(batch)
 
     def _serve_pending(self, batch: list) -> None:
-        t_go = time.perf_counter()
-        waited = sum(t_go - p.t_in for p in batch)
-        nodes = np.asarray([p.node for p in batch], np.int64)
-        try:
-            with self._serve_lock:
-                out = self._serve_locked(nodes)
-        except BaseException as e:      # deliver, don't kill the loop
-            for p in batch:
-                p.error = e
-                p.done.set()
-            return
-        t_end = time.perf_counter()
-        for i, p in enumerate(batch):
-            p.result = out[i]
-            self.stats.latencies_s.append(t_end - p.t_in)
-            p.done.set()
-        self.stats.record_batch(len(batch), waited)
+        with span("serve.batch", batch=next(self._batch_ids),
+                  requests=len(batch)) as served:
+            t_go = served.start_ns / 1e9     # perf_counter's clock
+            waited = sum(t_go - p.t_in for p in batch)
+            nodes = np.asarray([p.node for p in batch], np.int64)
+            try:
+                with self._locked():
+                    out = self._serve_locked(nodes)
+            except BaseException as e:      # deliver, don't kill the loop
+                for p in batch:
+                    p.error = e
+                    p.done.set()
+                return
+            with span("serve.respond") as respond:
+                t_end = respond.start_ns / 1e9
+                for i, p in enumerate(batch):
+                    p.result = out[i]
+                    self.stats.latencies_s.append(t_end - p.t_in)
+                    p.done.set()
+            self.stats.record_batch(len(batch), waited)
 
     # -- contracts / observability ---------------------------------------------
 
@@ -408,6 +441,9 @@ class GNNServer:
             self._hit_step.assert_compiled_per_bucket()
 
     def server_stats(self) -> dict:
+        """Counters and stage totals since the server was built, cache
+        stats, the trace certificates, and ``latency_ms`` percentiles
+        over the latest ``LATENCY_WINDOW`` requests."""
         s = self.stats.summary()
         s["cache"] = (self.cache.stats() if self.cache is not None
                       else {"enabled": False})

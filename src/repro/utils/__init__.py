@@ -7,7 +7,7 @@ from repro.utils.tree import (
     tree_add,
     tree_scale,
 )
-from repro.utils.timing import Timer, timed
+from repro.utils.timing import span
 from repro.utils.logging import get_logger
 
 __all__ = [
@@ -18,7 +18,6 @@ __all__ = [
     "tree_global_norm",
     "tree_add",
     "tree_scale",
-    "Timer",
-    "timed",
+    "span",
     "get_logger",
 ]
