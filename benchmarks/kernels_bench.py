@@ -76,21 +76,18 @@ def _sum_stage_traffic():
     """Fused-gather kernel vs the PR-1 pre-gather path: wall-clock and
     message-bytes moved through the Sum stage.
 
-    The pre-gather path is reconstructed exactly: materialize the padded
-    ``(nb, L_pad, D)`` layout in HBM, then run the same kernel over it with
-    an identity gather (contiguous reads) — which is what PR 1 shipped.
-    Also asserts (via the jaxpr) that the live fused path never allocates
-    that layout.
+    The pre-gather path is reconstructed over the same packed plan:
+    materialize the ``(n_chunks, BE, D)`` layout in HBM, then run the same
+    kernel over it with an identity gather (contiguous reads) — what PR 1
+    shipped, on today's chunk layout. Also asserts (via the jaxpr) that
+    the live fused path never allocates that layout.
 
     The graph is **skew-degree** (half the edges land on one destination
-    block), the regime where pre-gathering hurts most: every block's edge
-    slice pads to the hottest block's length, so the pre-gathered layout
-    holds nb·L_pad ≈ 17·E message rows while the fused kernels keep
-    reading the raw E rows. Interpret-mode wall-clock under-sells the gap
-    (the Python emulation is per-grid-step bound, not bandwidth bound —
-    on a uniform-degree graph, where nb·L_pad ≈ 1.2·E, it is a tie within
-    noise) but at this skew the fused path wins it consistently; the
-    bytes columns carry the hardware-relevant ratio.
+    block). The packed layout holds ``n_chunks·BE`` message rows, E plus
+    under one chunk a block, so the pre-gather path moves about three
+    times the fused path's message bytes whatever the skew.
+    Interpret-mode wall-clock is per-grid-step bound, not bandwidth
+    bound; the bytes columns carry the hardware-relevant ratio.
     """
     import functools
 
@@ -103,7 +100,8 @@ def _sum_stage_traffic():
     ids = np.concatenate([hot, cold]).astype(np.int32)
     data = jnp.asarray(rng.normal(size=(E, D)), jnp.float32)
     plan = build_csc_plan(ids, N)
-    nb, l_pad = plan.gather_idx.shape
+    nb, n_chunks = plan.num_blocks, plan.gather_idx.shape[0]
+    lanes = n_chunks * plan.block_e
 
     # jit the fused wrapper so both sides time compiled dispatch (the
     # pregather emulation below is @jax.jit)
@@ -112,13 +110,13 @@ def _sum_stage_traffic():
     _check(jax.make_jaxpr(fused)(data), plan, ["jaxpr.pregather"])
     us_fused = _best_of(fused, data)
 
-    ident = np.arange(nb * l_pad, dtype=np.int32).reshape(nb, l_pad)
+    ident = np.arange(lanes, dtype=np.int32).reshape(plan.gather_idx.shape)
 
     @jax.jit
     def pregather(d):
         padded = jnp.concatenate([d, jnp.zeros((1, D), d.dtype)], axis=0)
-        gathered = padded[jnp.asarray(plan.gather_idx)]   # (nb, L_pad, D)
-        return segment_sum_csc(gathered.reshape(nb * l_pad, D),
+        gathered = padded[jnp.asarray(plan.gather_idx)]   # (n_chunks, BE, D)
+        return segment_sum_csc(gathered.reshape(lanes, D),
                                jnp.asarray(ident),
                                jnp.asarray(plan.local_ids), nb,
                                plan.block_n, plan.block_e,
@@ -132,12 +130,12 @@ def _sum_stage_traffic():
          f"E={E};N={N};D={D};pregather_us={us_pre:.0f}")
     return {
         "edges": E, "num_segments": N, "feature_dim": D,
-        "plan_blocks": nb, "plan_l_pad": l_pad,
+        "plan_blocks": nb, "plan_chunks": n_chunks,
         # bytes of message data crossing HBM for one Sum-stage call:
         # fused reads the raw (E, D) once; pre-gather reads it, writes the
-        # padded (nb, L_pad, D) layout, then the kernel reads that back
+        # (n_chunks, BE, D) layout, then the kernel reads that back
         "fused_message_bytes": 4 * E * D,
-        "pregather_message_bytes": 4 * (E * D + 2 * nb * l_pad * D),
+        "pregather_message_bytes": 4 * (E * D + 2 * lanes * D),
         "fused_us_per_call": round(us_fused, 1),
         "pregather_us_per_call": round(us_pre, 1),
         "fused_beats_pregather": bool(us_fused < us_pre),
@@ -415,8 +413,8 @@ def aggregate(out_json: str = "BENCH_aggregate.json", smoke: bool = False):
                             "(Python emulation, not kernel speed); the "
                             "trajectory is meaningful per backend/device. "
                             "csc rows are fused-gather, forward and "
-                            "backward: verified free of the (nb, L_pad, "
-                            "D) pre-gather tensor via jaxpr walk, and the "
+                            "backward: verified free of the (n_chunks, "
+                            "BE, D) pre-gather tensor via jaxpr walk, and the "
                             "train step carries no Sum-stage reference "
                             "segment fallbacks"),
                    "sum_stage_traffic": traffic,

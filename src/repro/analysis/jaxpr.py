@@ -12,8 +12,9 @@ benches, and the ``python -m repro.analysis`` CI gate alike.
 Rule catalog (jaxpr family):
 
 =======================  ====================================================
-``jaxpr.pregather``      no ``(nb, L_pad, ...)`` float aval — the pre-gathered
-                         message layout the fused kernels eliminated
+``jaxpr.pregather``      no ``(n_chunks, BE, ...)`` float aval — the
+                         pre-gathered message layout the fused kernels
+                         eliminated
 ``jaxpr.segment-scatter``no scatter primitive whose updates carry the plan's
                          edge axis (a reference ``jax.ops.segment_*`` call)
 ``jaxpr.backward-gather``no ``(N, ...) -> (E, ...)`` gather outside the
@@ -268,18 +269,18 @@ def count_segment_scatters(closed_jaxpr, plan) -> int:
 
 
 @rule("jaxpr.pregather",
-      "no (nb, L_pad, ...) float aval — the pre-gathered message layout "
-      "the fused kernels eliminated")
+      "no (n_chunks, BE, ...) float aval — the pre-gathered message "
+      "layout the fused kernels eliminated")
 def _check_pregather(ctx: JaxprContext) -> List[Finding]:
     if ctx.plan is None:
         return []
-    nb, l_pad = ctx.plan.gather_idx.shape[-2:]
+    n_chunks, block_e = ctx.plan.gather_idx.shape[-2:]
     findings = []
     # kernel-body values are VMEM tiles, not HBM tensors: a (1, BE) tile
-    # collides with (nb, L_pad) when nb == 1 and L_pad == BE
+    # collides with (n_chunks, BE) when the plan has one chunk
     for aval in jaxpr_avals(ctx.closed_jaxpr, skip_pallas_bodies=True):
         shape = tuple(getattr(aval, "shape", ()))
-        if len(shape) < 2 or shape[:2] != (nb, l_pad):
+        if len(shape) < 2 or shape[:2] != (n_chunks, block_e):
             continue
         pregather = len(shape) >= 3 or jnp.issubdtype(
             getattr(aval, "dtype", jnp.int32), jnp.floating)
@@ -287,7 +288,8 @@ def _check_pregather(ctx: JaxprContext) -> List[Finding]:
             findings.append(Finding(
                 "jaxpr.pregather",
                 f"pre-gathered message tensor {shape} found in jaxpr "
-                f"(plan: nb={nb}, L_pad={l_pad})", label=ctx.label))
+                f"(plan: n_chunks={n_chunks}, BE={block_e})",
+                label=ctx.label))
     return findings
 
 

@@ -28,7 +28,7 @@ a call without one raises :class:`MissingPlanError` rather than quietly
 running the reference ops. The kernels read the plan's index chunks into
 SMEM and gather message rows from HBM by DMA — the kernel path consumes
 the raw ``(E, H, D)`` messages directly, with no pre-gathered
-``(nb, L_pad, D)`` intermediate (and multi-head softmax is one launch).
+``(n_chunks, BE, D)`` intermediate (and multi-head softmax is one launch).
 Kernel forwards are paired with fused Pallas
 ``custom_vjp`` backwards (:mod:`repro.kernels.backward`): a plan-driven
 gather kernel for sum, the same gather plus an in-kernel argmax-hit mask
@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ops import (CSCPlan, default_interpret,
+from repro.kernels.ops import (CSCPlan, default_interpret, num_plan_blocks,
                                edge_softmax_bwd_op,
                                edge_softmax_fwd_op, edge_softmax_op,
                                segment_max_bwd_op, segment_max_op,
@@ -152,7 +152,7 @@ def _plan_from_children(plan_children, meta, num_segments, num_edges):
     backward kernels can scalar-prefetch them)."""
     bn, be, _ = meta
     return CSCPlan(plan_children[0], plan_children[1], plan_children[2],
-                   plan_children[0].shape[0], bn, be, num_segments,
+                   num_plan_blocks(num_segments, bn), bn, be, num_segments,
                    num_edges)
 
 

@@ -21,8 +21,8 @@ selected :class:`AggregationBackend` (``"reference"`` jnp segment ops or
 the ``"csc"`` Pallas kernels over per-shard cached CSCPlans) and are
 finalized through a :class:`ShardContext` wrapping the halo exchange.
 The stacked plan arrays staged here (``csc_gather``/``csc_local``,
-(P, nb, L) with identical padded shapes across shards) feed the kernels
-directly as scalar-prefetch operands — the per-edge gather is fused into
+(P, n_chunks, BE), padded to one chunk count across shards) feed the
+kernels directly — the per-edge gather is fused into
 the kernel grid, so no shard ever materializes a pre-gathered message
 tensor.
 """

@@ -77,8 +77,8 @@ class PartitionPlan:
     def csc_plans(self, block_n: int = 128, block_e: int = 256):
         """One CSCPlan per partition over its local destination ids
         (segments = the shard's [masters ; mirrors] axis), all with
-        identical padded shapes so the engine can stack them (P, nb, L)
-        and shard them over the worker group. The stacked index arrays
+        identical padded shapes so the engine can stack them (P,
+        n_chunks, BE) and shard them over the worker group. The stacked index arrays
         are exactly what the fused-gather kernels scalar-prefetch — the
         shard's raw edge messages are never re-laid-out on device."""
         key = (block_n, block_e)

@@ -20,8 +20,8 @@ node-indexed arrays (cotangent ``g``, saved forward output, softmax
 stats) stay in HBM and each edge's row is copied into VMEM by a row DMA
 (:func:`~repro.kernels.segment_sum.gather_rows`), addressed by the plan's
 **inverse map** ``edge_dst`` — built host-side in ``build_csc_plan`` by
-inverting ``gather_idx``/``local_ids`` (lane ``(b, l)`` holds edge
-``gather_idx[b, l]`` destined for row ``b*block_n + local_ids[b, l]``).
+inverting ``gather_idx``/``local_ids`` (live lane ``(c, l)`` holds edge
+``gather_idx[c, l]`` destined for row ``local_ids[c, l]``).
 Each chunk of ``edge_dst`` is copied to SMEM for the DMA addresses and
 also read as a ``(BE, 1)`` column: lanes whose destination is outside
 the plan (``num_segments``: pad lanes, and edges no block aggregates)

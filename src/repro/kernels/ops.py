@@ -44,7 +44,15 @@ def _interp(interpret) -> bool:
 
 @dataclass(frozen=True)
 class CSCPlan:
-    """Per-graph padded edge layout for the blocked aggregation kernels.
+    """Per-graph packed edge layout for the blocked aggregation kernels.
+
+    Destinations are tiled into ``num_blocks`` blocks of ``block_n`` rows.
+    Each block's in-edges, in CSC order, fill whole ``block_e``-lane
+    chunks, ``max(1, ceil(len_b / block_e))`` of them (the one chunk of an
+    empty block is what writes its output rows), packed back to back;
+    padded plans end in dead chunks. The forward kernels read each chunk's
+    block from the plan's step table (``segment_sum.step_table``), so they
+    walk only chunks that hold edges.
 
     Built once per graph (the paper's reused CSC indexing); all views and
     batches reuse it — only the per-edge messages change between steps.
@@ -52,10 +60,14 @@ class CSCPlan:
     is static aux data) so plans ride along GraphBlocks and engine shards
     through ``jit`` / ``shard_map`` / ``grad``.
     """
-    gather_idx: np.ndarray    # (nb, L_pad) int32 into edge axis (E = pad
-    #                           lane; the fused kernels clip it and the
-    #                           local_ids masking nulls its contribution)
-    local_ids: np.ndarray     # (nb, L_pad) int32 in [0, BN]; BN = padding
+    gather_idx: np.ndarray    # (n_chunks, BE) int32 into the edge axis
+    #                           (dead lanes hold num_edges; the kernels
+    #                           never gather them)
+    local_ids: np.ndarray     # (n_chunks, BE) int32: a live lane's
+    #                           destination row (block b holds rows
+    #                           [b*BN, (b+1)*BN)); a dead lane of block b
+    #                           holds -1 - b, of a trailing chunk
+    #                           -1 - num_blocks
     edge_dst: np.ndarray      # (E_pad,) int32: the plan's inverse map,
     #                           lane e = destination row of edge e (pad
     #                           lanes hold num_segments) — drives the
@@ -80,46 +92,59 @@ def _plan_unflatten(aux, children):
 jax.tree_util.register_pytree_node(CSCPlan, _plan_flatten, _plan_unflatten)
 
 
+def num_plan_blocks(num_segments: int, block_n: int) -> int:
+    """Destination blocks of a plan over ``num_segments`` rows."""
+    return -(-num_segments // block_n)
+
+
 def build_csc_plan(segment_ids: np.ndarray, num_segments: int,
                    block_n: int = 128, block_e: int = 256,
-                   l_pad: int = 0) -> CSCPlan:
-    """``l_pad`` > 0 forces the padded edge-slice length (so plans built for
-    different shards of one graph stack into a single (P, nb, L) array)."""
+                   n_chunks: int = 0) -> CSCPlan:
+    """The packed plan over ``segment_ids`` (ids outside ``[0,
+    num_segments)`` join no block). ``n_chunks`` > 0 pads it with dead
+    chunks to that many (so plans built for one bucket, or for the shards
+    of one graph, share a shape)."""
     ids = np.asarray(segment_ids)
     E = len(ids)
     order = np.argsort(ids, kind="stable").astype(np.int64)
     sorted_ids = ids[order]
-    nb = (num_segments + block_n - 1) // block_n
-    starts = np.searchsorted(sorted_ids, np.arange(nb) * block_n)
-    ends = np.searchsorted(sorted_ids, np.minimum((np.arange(nb) + 1)
-                                                  * block_n, num_segments))
-    lens = ends - starts
-    l_max = int(lens.max()) if nb else 0
-    l_min = max(block_e, ((l_max + block_e - 1) // block_e) * block_e)
-    if l_pad:
-        if l_pad < l_min or l_pad % block_e != 0:
-            raise ValueError(
-                f"forced l_pad={l_pad} must be a block_e={block_e} "
-                f"multiple covering the widest block slice (>= {l_min})")
-    else:
-        l_pad = l_min
-    gather = np.full((nb, l_pad), E, np.int32)          # E = pad lane
-    local = np.full((nb, l_pad), block_n, np.int32)     # BN = dead row
-    for b in range(nb):
-        sl = order[starts[b]:ends[b]]
-        gather[b, :lens[b]] = sl
-        local[b, :lens[b]] = ids[sl] - b * block_n
-    # the inverse map the backward kernels scalar-prefetch: lane (b, l)
-    # holds edge gather[b, l] destined for row b*block_n + local[b, l],
-    # so inverting the plan gives each edge its destination row. Padded
-    # to a block_e multiple (pad lanes = num_segments, clip-gathered).
-    e_pad = max(block_e, ((E + block_e - 1) // block_e) * block_e)
+    nb = num_plan_blocks(num_segments, block_n)
+    bounds = np.searchsorted(
+        sorted_ids, np.minimum(np.arange(nb + 1) * block_n, num_segments))
+    lens = np.diff(bounds)
+    per_block = np.maximum(1, -(-lens // block_e))
+    first = np.concatenate([[0], np.cumsum(per_block)])
+    live = int(first[-1])
+    if n_chunks and n_chunks < live:
+        raise ValueError(
+            f"forced n_chunks={n_chunks} is below the {live} chunks the "
+            f"blocks need")
+    n_chunks = n_chunks or live
+    gather = np.full((n_chunks, block_e), E, np.int32)
+    local = np.full((n_chunks, block_e), -1 - nb, np.int32)
+    local[:live] = np.repeat(-1 - np.arange(nb, dtype=np.int32),
+                             per_block)[:, None]
+    # sorted edge k of block b lands on lane first[b]*BE + (k - bounds[b])
+    blk = np.repeat(np.arange(nb), lens)
+    lane = first[blk] * block_e + np.arange(len(blk)) - bounds[blk]
+    gather.flat[lane] = order[bounds[0]:bounds[-1]]
+    local.flat[lane] = sorted_ids[bounds[0]:bounds[-1]]
+    # the inverse map the backward kernels scalar-prefetch: each live
+    # lane names its edge and that edge's destination row. Padded to a
+    # block_e multiple (pad lanes = num_segments, clip-gathered).
+    e_pad = max(block_e, -(-E // block_e) * block_e)
     edge_dst = np.full(e_pad, num_segments, np.int32)
-    valid = local < block_n
-    rows = np.arange(nb, dtype=np.int32)[:, None] * block_n + local
-    edge_dst[gather[valid]] = rows[valid]
+    edge_dst[gather.flat[lane]] = local.flat[lane]
     return CSCPlan(gather, local, edge_dst, nb, block_n, block_e,
                    num_segments, E)
+
+
+def bucket_plan_chunks(n_pad: int, e_pad: int, block_n: int = 128,
+                       block_e: int = 256) -> int:
+    """The chunk count of every plan of an ``(n_pad, e_pad)`` bucket:
+    ``ceil(e_pad / BE) + nb`` covers any view, since a block of ``len_b``
+    edges takes ``max(1, ceil(len_b / BE)) <= len_b // BE + 1`` chunks."""
+    return -(-e_pad // block_e) + num_plan_blocks(n_pad, block_n)
 
 
 def build_bucket_csc_plan(dst_local: np.ndarray, n_pad: int, e_pad: int,
@@ -127,13 +152,23 @@ def build_bucket_csc_plan(dst_local: np.ndarray, n_pad: int, e_pad: int,
                           block_e: int = 256) -> CSCPlan:
     """Bucket-shape-stable plan over a compact view's local destination
     ids: every plan built for one ``(n_pad, e_pad)`` bucket has identical
-    leaf shapes AND identical static geometry (``num_blocks``/``l_pad``/
-    ``num_edges`` derive from the bucket, not the view), so a jitted step
-    taking the plan as a pytree caches exactly one executable per bucket.
+    leaf shapes AND identical static geometry (``num_blocks`` and the
+    chunk count, :func:`bucket_plan_chunks`, derive from the bucket, not
+    the view), so a jitted step taking the plan as a pytree caches exactly
+    one executable per bucket. The chunks the view does not need are dead
+    and cost the forward kernels one table read each.
 
-    Pad lanes carry segment id ``n_pad`` — outside every block's range, so
-    pad edges join no gather block; their values are additionally nulled
-    by the block's ``edge_mask`` like any padded edge."""
+    =================  ======  ===============  =======================
+    bucket             blocks  chunks (lanes)   step table (SMEM words)
+    =================  ======  ===============  =======================
+    (4,096, 16,384)    32      96 (24,576)      129
+    (16,384, 65,536)   128     384 (98,304)     513
+    (32,768, 131,072)  256     768 (196,608)    1,025
+    =================  ======  ===============  =======================
+
+    Pad edges carry segment id ``n_pad`` — outside every block's range, so
+    they join no block; their values are additionally nulled by the
+    block's ``edge_mask`` like any padded edge."""
     e = len(dst_local)
     if e > e_pad:
         raise ValueError(
@@ -144,32 +179,22 @@ def build_bucket_csc_plan(dst_local: np.ndarray, n_pad: int, e_pad: int,
             f"bucket's n_pad={n_pad}")
     ids = np.full(e_pad, n_pad, np.int32)
     ids[:e] = dst_local
-    # worst case all e_pad edges land in one node block: forcing l_pad to
-    # that bound makes the lane-axis shape a pure function of the bucket
-    l_pad = max(block_e, ((e_pad + block_e - 1) // block_e) * block_e)
-    return build_csc_plan(ids, n_pad, block_n, block_e, l_pad=l_pad)
+    return build_csc_plan(
+        ids, n_pad, block_n, block_e,
+        n_chunks=bucket_plan_chunks(n_pad, e_pad, block_n, block_e))
 
 
 def build_csc_plans_stacked(segment_ids_rows, num_segments: int,
                             block_n: int = 128, block_e: int = 256):
-    """One plan per row of ``segment_ids_rows`` (P, E), all with identical
-    padded shapes — the per-shard reused plans of the distributed engine."""
+    """One plan per row of ``segment_ids_rows`` (P, E), all padded to the
+    largest chunk count — the per-shard reused plans of the distributed
+    engine, stacked to (P, n_chunks, BE)."""
     rows = [np.asarray(r) for r in segment_ids_rows]
     plans = [build_csc_plan(r, num_segments, block_n, block_e) for r in rows]
-    l_pad = max(p.gather_idx.shape[1] for p in plans)
-
-    def widen(p: CSCPlan) -> CSCPlan:
-        extra = l_pad - p.gather_idx.shape[1]
-        if not extra:
-            return p
-        gather = np.pad(p.gather_idx, ((0, 0), (0, extra)),
-                        constant_values=p.num_edges)     # pad lane
-        local = np.pad(p.local_ids, ((0, 0), (0, extra)),
-                       constant_values=p.block_n)        # dead lane
-        return CSCPlan(gather, local, p.edge_dst, p.num_blocks, p.block_n,
-                       p.block_e, p.num_segments, p.num_edges)
-
-    return [widen(p) for p in plans]
+    n_chunks = max(p.gather_idx.shape[0] for p in plans)
+    return [p if p.gather_idx.shape[0] == n_chunks else
+            build_csc_plan(r, num_segments, block_n, block_e, n_chunks)
+            for r, p in zip(rows, plans)]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -177,11 +202,12 @@ def build_csc_plans_stacked(segment_ids_rows, num_segments: int,
 def _segment_reduce_planned(data, gather_idx, local_ids, num_segments: int,
                             block_n: int, block_e: int, interpret: bool,
                             op: str = "sum"):
-    # the gather is fused into the kernels (scalar-prefetched plan indices)
-    # — no (nb, L_pad, D) pre-gathered tensor is materialized here anymore
+    # the gather is fused into the kernels (plan chunks copied to SMEM)
+    # — no (n_chunks, BE, D) pre-gathered tensor is materialized here
     kern = segment_sum_csc if op == "sum" else segment_max_csc
-    out = kern(data, gather_idx, local_ids, gather_idx.shape[0],
-               block_n, block_e, interpret=interpret)
+    out = kern(data, gather_idx, local_ids,
+               num_plan_blocks(num_segments, block_n), block_n, block_e,
+               interpret=interpret)
     return out[:num_segments]
 
 
@@ -288,8 +314,8 @@ def segment_max_bwd_op(g: jax.Array, fwd_out: jax.Array, data: jax.Array,
 def assert_pregather_free(closed_jaxpr, plan: CSCPlan):
     """Shim over the ``jaxpr.pregather`` registry rule: the traced
     computation never allocates a tensor shaped like the pre-gathered
-    (nb, L_pad, ...) message layout the fused kernels eliminated —
-    including the 2-D *float* (nb, L_pad) layout the old edge-softmax
+    (n_chunks, BE, ...) message layout the fused kernels eliminated —
+    including the 2-D *float* (n_chunks, BE) layout the old edge-softmax
     path used for gathered logits. The integer 2-D plan index arrays
     (gather_idx/local_ids) are expected and allowed."""
     check_or_raise(run_rules(JaxprContext(closed_jaxpr, plan=plan),
@@ -300,7 +326,7 @@ def assert_sum_stage_fused(closed_jaxpr, plan: CSCPlan):
     """Shim over the full Sum-stage ruleset on the csc path, forward AND
     backward:
 
-    1. ``jaxpr.pregather`` — no ``(nb, L_pad, ...)`` float tensor;
+    1. ``jaxpr.pregather`` — no ``(n_chunks, BE, ...)`` float tensor;
     2. ``jaxpr.segment-scatter`` — no scatter primitive whose updates
        carry the edge axis (the forward fallback's ``.at[ids].add/max``
        and the softmax recompute's segment passes);
@@ -399,8 +425,8 @@ def _edge_softmax_planned(logits, values, gather_idx, local_ids,
     # launch also yields the per-destination softmax stats (m, den) the
     # recompute-in-kernel backward rebuilds p_e from.
     out, m, den = edge_softmax_csc(logits, values, gather_idx, local_ids,
-                                   gather_idx.shape[0], block_n, block_e,
-                                   interpret=interpret)
+                                   num_plan_blocks(num_segments, block_n),
+                                   block_n, block_e, interpret=interpret)
     return out[:num_segments], m[:num_segments], den[:num_segments]
 
 

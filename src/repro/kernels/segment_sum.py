@@ -3,14 +3,11 @@
 The paper's stage breakdown (Fig. A3) shows graph convolution — dominated
 by the per-edge gather + per-destination aggregation — at 76% of runtime.
 On GPU this is a scatter-add; here edges are sorted by destination (the
-CSC order GraphTheta already maintains, §4.1), destinations are tiled
-into blocks of ``BN`` rows, and each destination block owns a contiguous
-padded slice of edges, processed in ``BE``-sized chunks by a sequential
-grid axis that revisits the same ``(BN, BD)`` output tile in VMEM. Within
-a chunk the kernel folds each gathered message row into its destination
-row, lane by lane in plan order::
+CSC order GraphTheta already maintains, §4.1) and destinations are tiled
+into blocks of ``BN`` rows. Within a chunk the kernel folds each gathered
+message row into its destination row, lane by lane in plan order::
 
-    out[local_dst[l]] = op(out[local_dst[l]], messages[gather_idx[l]])
+    out[dst[l]] = op(out[dst[l]], messages[gather_idx[l]])
 
 with ``op`` = add (sum) or max. A destination's edges are folded in
 edge-id order whatever the block or chunk boundaries, so a node's
@@ -20,18 +17,42 @@ matmul form, ``onehot[BN, BE] @ messages[BE, D]`` on the MXU, groups the
 additions by chunk and loses that property; it is left to a later
 measured change.)
 
+Packed plan and step table
+--------------------------
+The plan (``ops.build_csc_plan``) lays each destination block's edge
+slice, in CSC order, into whole ``BE``-lane chunks: ``max(1, ceil(len_b /
+BE))`` chunks a block, packed back to back into ``(n_chunks, BE)`` index
+arrays. An empty block still takes one (dead) chunk: its step is what
+initialises the block's output rows. Lanes past a block's last edge are
+dead (``local_ids < 0``). A bucket's plans pad ``n_chunks`` with trailing
+dead chunks to ``ceil(e_pad / BE) + nb``, a bound every view meets, so
+their shapes depend on the bucket alone. A plan never holds more lanes
+than the unpacked layout of ``nb`` slices of the widest block's length:
+``Σ_b max(1, ceil(len_b / BE)) · BE <= nb · L_pad``.
+
+The forward grid walks chunks: sum and max run
+``(d_tiles, n_chunks)``, one step a chunk; the softmax runs ``(2 ·
+n_chunks,)``, a block's phase-0 steps before its phase-1 steps.
+:func:`step_table` derives, from each chunk's first lane, the block of
+every chunk and the first chunk of every block; both are scalar
+prefetched into SMEM, and the output ``index_map`` reads a step's block
+from them. A block's steps are contiguous, so its output tile stays
+resident in VMEM from its first step (which initialises it) to its last
+(the softmax writes its outputs there). Steps past the last live chunk
+do nothing: no SMEM copy, no lane loop, and the last block's tile stays
+put.
+
 Fused gather
 ------------
 The per-edge gather happens **inside** the kernel: the raw ``(E, D)`` edge
 messages stay in HBM (``memory_space=pl.ANY``) and each grid step copies
 its chunk's ``BE`` plan indices into SMEM, then issues one row DMA per
 lane into a ``(BE, BD)`` VMEM scratch (:func:`gather_rows`). There is no
-``(nb, L_pad, D)`` pre-gathered tensor in HBM (that tensor duplicated
-every message byte and dominated Sum-stage memory traffic; see
-``benchmarks/kernels_bench.py aggregate`` for the bytes-moved comparison).
-Padding lanes (``local_id == BN``) are never gathered nor folded: each
-step also copies the chunk's local ids to SMEM and issues DMAs only for
-live lanes (skewed plans are mostly padding).
+``(n_chunks, BE, D)`` pre-gathered tensor in HBM (that tensor duplicated
+every message byte; see ``benchmarks/kernels_bench.py aggregate`` for the
+bytes-moved comparison). Dead lanes are never gathered nor folded: each
+step also copies the chunk's destination rows to SMEM and issues DMAs
+only for live lanes.
 
 Mosaic layout rules shape the operands (every block's last two dims are
 (8, 128)-aligned or whole):
@@ -41,7 +62,7 @@ Mosaic layout rules shape the operands (every block's last two dims are
   one-row DMA must not cut an (8, 128) tile, and the unit middle axis
   gives each row a ``(1, 128)`` tiling. At ``D % 128 == 0`` the view is a
   bitcast of the ``(E, D)`` array.
-- Plan index chunks are ``(nb * n_chunks, 1, BE)`` int32: one chunk is a
+- Plan index chunks are ``(n_chunks, 1, BE)`` int32: one chunk is a
   whole ``(1, BE)`` tile, copied to SMEM (indices drive DMA addresses and
   the fold's row, and only scalars load from SMEM). Rows of VMEM refs
   are addressed with dynamic sublane offsets.
@@ -56,6 +77,8 @@ Per grid step the forward kernels hold, in f32:
 buffer                 shape                    bytes (defaults)
 =====================  =======================  =========================
 messages               (E, 1, Dp) in HBM        0 (never resident)
+step table (SMEM)      (n_chunks + nb + 1,)     4·(n_chunks + nb + 1)
+                       int32, whole launch      (training rung: 2 KiB)
 plan chunk (SMEM)      (1, BE) int32            4·BE
 local ids (SMEM)       (1, BE) int32            4·BE
 gather scratch         (BE, BD)                 4·BE·BD (256·512 → 512 KiB)
@@ -66,8 +89,8 @@ output tile            (BN, BD)                 4·BN·BD (128·512 → 256 KiB)
 lanes), else the largest multiple of 128 within the cap that divides it;
 the d-tile grid axis covers the rest. The edge softmax (edge_softmax.py)
 holds two ``(BE, H·D)`` gather buffers and three ``(BN, H·D)``
-accumulators. Nothing scales with E or N, so the kernels run at any
-graph size.
+accumulators. Only the SMEM step table grows with the graph, by one
+word a chunk and a block; the VMEM footprint does not.
 
 Backward geometry (kernels in backward.py)
 ------------------------------------------
@@ -91,8 +114,8 @@ packed row of per-destination stats ``[m | den | out·g]`` per edge, and
 rebuilds the edge probability in registers from the saved logits — it is
 never written to HBM.
 
-Host-side planning (``build_csc_plan`` in ops.py) computes the padded
-edge-slice layout once per graph — the paper's "reused CSR/CSC indexing"
+Host-side planning (``build_csc_plan`` in ops.py) computes the packed
+chunk layout once per graph — the paper's "reused CSR/CSC indexing"
 (§4.2): views/batches reuse the plan, only messages change.
 
 The budget arithmetic above is not only documentation: the static
@@ -188,67 +211,97 @@ def gather_rows(idx_smem, src_hbm, buf, sem, num_rows: int, d0, width: int,
     jax.lax.fori_loop(0, buf.shape[0], wait, 0)
 
 
-def _check_plan(gather_idx, num_blocks: int, block_e: int):
-    nb, l_pad = gather_idx.shape
-    if nb != num_blocks or l_pad % block_e != 0:
+def _check_plan(gather_idx, local_ids, block_e: int) -> int:
+    n_chunks, lanes = gather_idx.shape
+    if local_ids.shape != gather_idx.shape or lanes != block_e:
         raise ValueError(
-            f"plan shape ({nb}, {l_pad}) inconsistent with "
-            f"num_blocks={num_blocks}, block_e={block_e}")
-    return l_pad // block_e
+            f"plan chunks {gather_idx.shape} / {local_ids.shape} are not "
+            f"(n_chunks, block_e={block_e})")
+    return n_chunks
 
 
-def fold_rows(ids_smem, n_lanes: int, block_n: int, body):
-    """``body(i, r)`` for every lane ``i`` whose destination row ``r`` is
-    in the block, in lane order — i.e. each destination folds its edges
+def step_table(local_ids, num_blocks: int, block_n: int):
+    """The packed plan's step table, scalar-prefetched into SMEM by the
+    forward kernels: ``chunk_block`` (n_chunks,), the block of each chunk
+    (trailing dead chunks keep the last block, so its output tile stays
+    resident), and ``block_start`` (num_blocks + 1,), the first chunk of
+    each block, ``block_start[num_blocks]`` the live chunk count.
+
+    A chunk's block is read from its first lane: live lanes fill a chunk
+    from lane 0 and name their destination row, and the one chunk of an
+    empty block (or a trailing dead chunk) holds ``-1 - block`` there."""
+    head = local_ids[:, 0]
+    block = jnp.where(head >= 0, head // block_n, -1 - head)
+    start = jnp.searchsorted(block, jnp.arange(num_blocks + 1,
+                                               dtype=block.dtype),
+                             method="scan_unrolled")
+    return (jnp.minimum(block, num_blocks - 1).astype(jnp.int32),
+            start.astype(jnp.int32))
+
+
+def fold_rows(ids_smem, n_lanes: int, row0, body):
+    """``body(i, r)`` for every live lane ``i`` (its destination row
+    ``row0 + r``), in lane order — i.e. each destination folds its edges
     in plan (edge-id) order, whatever the chunking."""
     def step(i, carry):
-        r = ids_smem[0, i]
+        v = ids_smem[0, i]
 
-        @pl.when(r < block_n)
+        @pl.when(v >= 0)
         def _():
-            body(i, r)
+            body(i, v - row0)
         return carry
 
     jax.lax.fori_loop(0, n_lanes, step, 0)
 
 
-def _segment_fold_kernel(idx_hbm, ids_hbm, msg_hbm, out_ref, idx_smem,
-                         ids_smem, buf, sem, *, op, identity: float,
-                         block_n: int, n_chunks: int, num_edges: int,
+def live_lane(ids_smem):
+    """The ``live`` predicate of :func:`gather_rows`: lane ``i`` holds an
+    edge."""
+    return lambda i: ids_smem[0, i] >= 0
+
+
+def _segment_fold_kernel(block_ref, start_ref, idx_hbm, ids_hbm, msg_hbm,
+                         out_ref, idx_smem, ids_smem, buf, sem, *, op,
+                         identity: float, num_blocks: int, num_edges: int,
                          block_d: int):
-    """One (node_block, d_tile, edge_chunk) grid step, gather fused in.
+    """One (d_tile, step) grid step: step ``s`` folds chunk ``s`` into its
+    block's output tile, gather fused in.
 
-    idx_hbm: (nb*nc, 1, BE) int32 plan chunks in HBM — rows of ``msg``
+    block_ref / start_ref: the step table (:func:`step_table`) in SMEM.
+    idx_hbm: (n_chunks, 1, BE) int32 plan chunks in HBM — rows of ``msg``
              feeding each lane.
-    ids_hbm: (nb*nc, 1, BE) int32 — local destination row of each lane in
-             [0, BN]; BN marks padding (never gathered, never folded).
+    ids_hbm: (n_chunks, 1, BE) int32 — destination row of each lane, < 0
+             for a dead lane (never gathered, never folded).
     msg_hbm: (E, 1, Dp) f32 raw edge messages in HBM.
-    out_ref: (BN, BD) f32 destination tile (revisited across chunks).
+    out_ref: (BN, BD) f32 destination tile (resident over its block's
+             chunks).
     """
-    b, dt, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    dt, s = pl.program_id(0), pl.program_id(1)
+    b = block_ref[s]
 
-    @pl.when(c == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, identity)
+    @pl.when(s < start_ref[num_blocks])     # trailing dead steps: nothing
+    def _step():
+        @pl.when(s == start_ref[b])
+        def _init():
+            out_ref[...] = jnp.full_like(out_ref, identity)
 
-    chunk = b * n_chunks + c
-    pltpu.sync_copy(idx_hbm.at[chunk], idx_smem)
-    pltpu.sync_copy(ids_hbm.at[chunk], ids_smem)
-    gather_rows(idx_smem, msg_hbm, buf, sem, num_edges, dt * block_d,
-                block_d, lambda i: ids_smem[0, i] < block_n)
+        pltpu.sync_copy(idx_hbm.at[s], idx_smem)
+        pltpu.sync_copy(ids_hbm.at[s], ids_smem)
+        gather_rows(idx_smem, msg_hbm, buf, sem, num_edges, dt * block_d,
+                    block_d, live_lane(ids_smem))
 
-    def fold(i, r):
-        out_ref[pl.ds(r, 1), :] = op(out_ref[pl.ds(r, 1), :],
-                                     buf[pl.ds(i, 1), :])
+        def fold(i, r):
+            out_ref[pl.ds(r, 1), :] = op(out_ref[pl.ds(r, 1), :],
+                                         buf[pl.ds(i, 1), :])
 
-    fold_rows(ids_smem, buf.shape[0], block_n, fold)
+        fold_rows(ids_smem, buf.shape[0], b * out_ref.shape[0], fold)
 
 
 def _segment_fold_csc(data, gather_idx, local_ids, num_blocks: int,
                       block_n: int, block_e: int, block_d: int,
                       interpret: bool, op, identity: float):
     e, d = data.shape
-    nc = _check_plan(gather_idx, num_blocks, block_e)
+    nc = _check_plan(gather_idx, local_ids, block_e)
     if e == 0:
         return jnp.full((num_blocks * block_n, d), identity, jnp.float32)
     msg = row_view(data)
@@ -257,10 +310,11 @@ def _segment_fold_csc(data, gather_idx, local_ids, num_blocks: int,
     if dp % bd != 0:
         raise ValueError(f"feature dim {dp} not divisible by block_d={bd}")
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(num_blocks, dp // bd, nc),
+        num_scalar_prefetch=2,
+        grid=(dp // bd, nc),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
-        out_specs=pl.BlockSpec((block_n, bd), lambda b, dt, c: (b, dt)),
+        out_specs=pl.BlockSpec((block_n, bd),
+                               lambda dt, s, blk, start: (blk[s], dt)),
         scratch_shapes=[pltpu.SMEM((1, block_e), jnp.int32),
                         pltpu.SMEM((1, block_e), jnp.int32),
                         pltpu.VMEM((block_e, bd), jnp.float32),
@@ -268,13 +322,13 @@ def _segment_fold_csc(data, gather_idx, local_ids, num_blocks: int,
     )
     out = pl.pallas_call(
         functools.partial(_segment_fold_kernel, op=op, identity=identity,
-                          block_n=block_n, n_chunks=nc, num_edges=e,
-                          block_d=bd),
+                          num_blocks=num_blocks, num_edges=e, block_d=bd),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_blocks * block_n, dp),
                                        jnp.float32),
         interpret=interpret,
-    )(chunk_view(gather_idx, block_e), chunk_view(local_ids, block_e), msg)
+    )(*step_table(local_ids, num_blocks, block_n),
+      chunk_view(gather_idx, block_e), chunk_view(local_ids, block_e), msg)
     return out[:, :d]
 
 
@@ -284,10 +338,10 @@ def segment_sum_csc(data: jax.Array, gather_idx: jax.Array,
     """Blocked segment-sum with the per-edge gather fused into the kernel.
 
     data:       (E, D) raw edge messages (no pre-gathered layout).
-    gather_idx: (num_blocks, L_pad) int32 plan indices into the edge axis
-                (pad lanes hold E; L_pad % block_e == 0).
-    local_ids:  (num_blocks, L_pad) int32 — destination row within block,
-                block_n for padding lanes.
+    gather_idx: (n_chunks, block_e) int32 packed plan chunks: indices
+                into the edge axis (dead lanes hold E).
+    local_ids:  (n_chunks, block_e) int32 — destination row of each lane,
+                negative for dead lanes (``ops.CSCPlan``).
     returns     (num_blocks * block_n, D) float32.
     """
     return _segment_fold_csc(data, gather_idx, local_ids, num_blocks,

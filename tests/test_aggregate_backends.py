@@ -187,6 +187,8 @@ for model_name, heads in (("gcn", 1), ("sage", 1), ("sage_max", 1),
     sg = build_partitions(g, 4, gcn_norm=gcn_norm)
     eng = HybridParallelEngine(model, sg)
     assert "csc_gather" in eng._device_data    # kernels actually staged
+    # the shards' packed plans stack: one chunk count for all four
+    assert len({p.gather_idx.shape for p in sg.plan.csc_plans()}) == 1
     lg = eng.make_loss_and_grad()
     views = [global_batch_view(g, 2),
              next(mini_batch_views(g, 2, batch_nodes=24, seed=1))]

@@ -383,5 +383,5 @@ def test_bare_assert_sweep_raises_valueerror():
     with pytest.raises(ValueError, match="edge axis"):
         segment_sum_op(jnp.ones((plan.num_edges + 1, 4), jnp.float32),
                        plan)
-    with pytest.raises(ValueError, match="l_pad"):
-        build_csc_plan(ids, 40, block_n=16, block_e=32, l_pad=7)
+    with pytest.raises(ValueError, match="n_chunks"):
+        build_csc_plan(ids, 40, block_n=16, block_e=32, n_chunks=1)

@@ -378,3 +378,181 @@ def test_edge_softmax_bwd_kernel_matches_reference(H, D):
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(d_values), np.asarray(ref_dv),
                                rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# packed CSC plans: layout, step table, bound, kernels on skewed plans
+# ---------------------------------------------------------------------------
+
+
+def _skewed_ids(case, n, e, rng):
+    """Destination ids: ``hot`` puts half the edges on one block's rows
+    (that block spans many chunks) and leaves gaps of empty blocks;
+    ``tail`` leaves the upper half of the rows empty."""
+    if case == "hot":
+        hot = rng.integers(0, 8, e // 2)
+        cold = rng.choice(np.arange(n)[(np.arange(n) // 16) % 3 == 0],
+                          e - e // 2)
+        return np.concatenate([hot, cold]).astype(np.int32)
+    return rng.integers(0, n // 2, e).astype(np.int32)
+
+
+# (case, nodes, edges, block_n, block_e, forced chunk count: 0 = live only)
+SKEWED = [("hot", 160, 900, 16, 32, 0), ("hot", 160, 900, 16, 32, 80),
+          ("tail", 200, 300, 32, 64, 0), ("tail", 200, 300, 32, 64, 25)]
+
+
+def _plan_case(case, n, e, bn, be, n_chunks, seed=0):
+    from repro.kernels.ops import build_csc_plan as build
+    ids = _skewed_ids(case, n, e, np.random.default_rng(seed))
+    return ids, build(ids, n, block_n=bn, block_e=be, n_chunks=n_chunks)
+
+
+@pytest.mark.parametrize("case", SKEWED, ids=str)
+def test_packed_plan_layout_and_step_table(case):
+    """Each block's edges, in CSC order (by destination, then edge id),
+    fill max(1, ceil(len/BE))
+    whole chunks packed back to back; dead lanes name their block
+    (-1 - b, trailing chunks -1 - nb); the step table reads every chunk's
+    block and every block's first chunk back from that."""
+    from repro.kernels.segment_sum import step_table
+    ids, plan = _plan_case(*case)
+    n, bn, be = case[1], case[3], case[4]
+    nb = plan.num_blocks
+    lens = np.bincount(ids // bn, minlength=nb)
+    per_block = np.maximum(1, -(-lens // be))
+    first = np.concatenate([[0], np.cumsum(per_block)])
+    assert plan.gather_idx.shape == plan.local_ids.shape
+    assert plan.gather_idx.shape == (case[5] or first[-1], be)
+    for b in range(nb):
+        lanes = slice(first[b] * be, first[b + 1] * be)
+        edges = plan.gather_idx.reshape(-1)[lanes][:lens[b]]
+        rows = plan.local_ids.reshape(-1)[lanes]
+        mine = np.flatnonzero(ids // bn == b)
+        np.testing.assert_array_equal(
+            edges, mine[np.argsort(ids[mine], kind="stable")])
+        np.testing.assert_array_equal(rows[:lens[b]], ids[edges])
+        assert np.all(rows[lens[b]:] == -1 - b)
+    assert np.all(plan.local_ids[first[-1]:] == -1 - nb)
+    assert np.all(plan.gather_idx.reshape(-1)[plan.local_ids.reshape(-1)
+                                              < 0] == len(ids))
+    block, start = map(np.asarray, step_table(
+        jnp.asarray(plan.local_ids), nb, bn))
+    np.testing.assert_array_equal(start, first)
+    np.testing.assert_array_equal(
+        block, np.minimum(np.repeat(np.arange(nb + 1), np.append(
+            per_block, len(plan.local_ids) - first[-1])), nb - 1))
+    np.testing.assert_array_equal(plan.edge_dst[:len(ids)], ids)
+    assert n == plan.num_segments
+
+
+@pytest.mark.parametrize("kernel", ["sum", "max", "softmax"])
+@pytest.mark.parametrize("case", SKEWED, ids=str)
+def test_packed_kernels_match_reference(case, kernel):
+    """Sum, d-tiled max (D = 200, lane-padded to 256, in two tiles of
+    128) and multi-head softmax over skewed packed plans: one block across
+    many chunks, empty blocks, trailing dead steps."""
+    from repro.kernels.ops import edge_softmax_op
+    from repro.kernels.ref import edge_softmax_ref
+    from repro.kernels.segment_sum import NEG, segment_max_csc
+    ids, plan = _plan_case(*case)
+    n, e = case[1], len(ids)
+    rng = np.random.default_rng(7)
+    if kernel == "softmax":
+        H, D = 3, 8
+        logits = jnp.asarray(rng.normal(size=(e, H)) * 3, jnp.float32)
+        vals = jnp.asarray(rng.normal(size=(e, H, D)), jnp.float32)
+        out = edge_softmax_op(logits, vals, plan, interpret=True)
+        for h in range(H):
+            np.testing.assert_allclose(
+                np.asarray(out[:, h]), np.asarray(edge_softmax_ref(
+                    logits[:, h], vals[:, h], jnp.asarray(ids), n)),
+                rtol=2e-5, atol=2e-5)
+        return
+    data = jnp.asarray(rng.normal(size=(e, 200)), jnp.float32)
+    if kernel == "sum":
+        out = segment_sum_op(data, plan, interpret=True)
+        ref = segment_sum_ref(data, jnp.asarray(ids), n)
+    else:
+        out = segment_max_csc(data, jnp.asarray(plan.gather_idx),
+                              jnp.asarray(plan.local_ids), plan.num_blocks,
+                              plan.block_n, plan.block_e, block_d=128,
+                              interpret=True)[:n, :200]
+        ref = jnp.maximum(jax.ops.segment_max(data, jnp.asarray(ids), n),
+                          NEG)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["sum", "max", "softmax"])
+def test_packed_kernels_are_chunk_invariant(kernel):
+    """A destination gives the same bits whether its block's edges sit in
+    one chunk or are split over many: each folds its edges in edge-id
+    order whatever the chunk width."""
+    from repro.kernels.ops import edge_softmax_op, segment_max_op
+    rng = np.random.default_rng(3)
+    n, e = 64, 700
+    ids = _skewed_ids("hot", n, e, rng)
+    logits = jnp.asarray(rng.normal(size=(e, 2)) * 3, jnp.float32)
+    vals = jnp.asarray(rng.normal(size=(e, 2, 8)), jnp.float32)
+    data = vals.reshape(e, 16)
+    outs = []
+    for be in (8, 32, 512):      # the hot block: 44 chunks .. one chunk
+        plan = build_csc_plan(ids, n, block_n=16, block_e=be)
+        if kernel == "softmax":
+            out = edge_softmax_op(logits, vals, plan, interpret=True)
+        elif kernel == "sum":
+            out = segment_sum_op(data, plan, interpret=True)
+        else:
+            out = segment_max_op(data, plan, interpret=True)
+        outs.append(np.asarray(out))
+    for out in outs[1:]:
+        np.testing.assert_array_equal(out, outs[0])
+
+
+@pytest.mark.parametrize("rung", [(256, 1024), (4096, 16384),
+                                  (16384, 65536)])
+def test_bucket_plan_chunk_bound(rung):
+    """Bucket plans take ceil(e_pad/BE) + nb chunks whatever the view —
+    edges all on one block, spread over every block, or none — and never
+    more lanes than the unpacked nb x L_pad layout of the same edges."""
+    from repro.kernels.ops import build_bucket_csc_plan, bucket_plan_chunks
+    n_pad, e_pad = rung
+    nb = n_pad // 128
+    bound = -(-e_pad // 256) + nb
+    assert bucket_plan_chunks(n_pad, e_pad) == bound
+    rng = np.random.default_rng(n_pad)
+    views = {"one_block": rng.integers(0, 128, e_pad),
+             "every_block": rng.integers(0, n_pad, e_pad - 1),
+             "bucket_full_skew": np.sort(rng.integers(0, n_pad, e_pad)),
+             "empty": np.zeros(0, np.int64)}
+    for name, dst in views.items():
+        plan = build_bucket_csc_plan(dst.astype(np.int32), n_pad, e_pad)
+        assert plan.gather_idx.shape == (bound, 256), name
+        lens = np.bincount(dst // 128, minlength=nb)
+        live = int(np.maximum(1, -(-lens // 256)).sum())
+        assert live <= bound, name
+        l_pad = max(256, -(-int(lens.max(initial=0)) // 256) * 256)
+        assert live * 256 <= nb * l_pad, name
+
+
+def test_stacked_plans_of_unequal_shards_share_a_shape():
+    """The engine's per-shard plans pad to the largest chunk count with
+    dead chunks, and each padded plan gives what its own unpadded plan
+    gives."""
+    from repro.kernels.ops import build_csc_plans_stacked
+    rng = np.random.default_rng(8)
+    n = 96
+    rows = [rng.integers(0, n, 400), rng.integers(0, 8, 400),
+            np.full(400, n, np.int64)]       # the last: no edge in range
+    plans = build_csc_plans_stacked(np.stack(rows).astype(np.int32), n,
+                                    block_n=32, block_e=32)
+    assert len({p.gather_idx.shape for p in plans}) == 1
+    data = jnp.asarray(rng.normal(size=(400, 8)), jnp.float32)
+    for ids, plan in zip(rows, plans):
+        own = build_csc_plan(ids.astype(np.int32), n, block_n=32,
+                             block_e=32)
+        assert own.gather_idx.shape[0] <= plan.gather_idx.shape[0]
+        np.testing.assert_array_equal(
+            np.asarray(segment_sum_op(data, plan, interpret=True)),
+            np.asarray(segment_sum_op(data, own, interpret=True)))

@@ -264,7 +264,10 @@ def test_view_stage_counts_plan_lanes_and_live_edges(tmp_path):
     (stage,) = by_name(timing.recorded(), "view.stage")
     plan = block.csc_plan
     assert stage.attrs["plan_lanes"] == plan.gather_idx.size
-    assert plan.gather_idx.size == plan.num_blocks * plan.gather_idx.shape[1]
+    # the packed plan's lanes as built: the bucket's chunk bound
+    from repro.kernels.ops import bucket_plan_chunks
+    assert plan.gather_idx.size == plan.block_e * bucket_plan_chunks(
+        plan.num_segments, plan.num_edges, plan.block_n, plan.block_e)
     assert stage.attrs["live_edges"] == len(view.dst_local)
 
 

@@ -5,7 +5,10 @@ Nothing runs here: each test lowers one kernel wrapper with
 the TPU compiler for the executable, at the widths the system runs on
 the chip — GAT-E messages (4 heads x 32) for the edge softmax, D = 128
 for sum and max — and at the ``alipay_like`` graph's edge and node
-counts with the plan geometry its CSC plan has. Mosaic refuses what the
+counts with the plan geometry its CSC plan has; the packed forward
+kernels also at the bucket rungs the benchmark's cells run, with their
+scalar-prefetched step tables at the bucket's chunk bound (the SMEM
+they take). Mosaic refuses what the
 interpreter accepts (blocks off the (8, 128) tiling, vector loads from
 SMEM, VMEM over budget), so this file is the guard that keeps the
 kernels compilable without a chip.
@@ -24,11 +27,13 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 
 # alipay_like (powerlaw_graph defaults): nodes, directed edges, and its
-# CSC plan geometry at the default blocks (128 x 256)
+# packed CSC plan geometry at the default blocks (128 x 256)
 N, E = 20000, 119862
-NB, L_PAD, E_PAD = 157, 9216, 120064
+N_CHUNKS, E_PAD = 537, 120064
 BLOCK_N, BLOCK_E = 128, 256
 HEADS, HEAD_DIM, D = 4, 32, 128
+# (n_pad, e_pad) bucket rungs: mini-batch training's, and serving's top
+RUNGS = [(16384, 65536), (32768, 131072)]
 
 
 @pytest.fixture(scope="module")
@@ -66,8 +71,8 @@ def test_segment_reduce_fwd_compiles(one_chip, op, width):
     # width HEADS: the distributed softmax's (E, H) logit passes
     _compile(ops._segment_reduce_planned,
              _spec(one_chip, (E, width)),
-             _spec(one_chip, (NB, L_PAD), jnp.int32),
-             _spec(one_chip, (NB, L_PAD), jnp.int32),
+             _spec(one_chip, (N_CHUNKS, BLOCK_E), jnp.int32),
+             _spec(one_chip, (N_CHUNKS, BLOCK_E), jnp.int32),
              num_segments=N, block_n=BLOCK_N, block_e=BLOCK_E,
              interpret=False, op=op)
 
@@ -91,10 +96,28 @@ def test_edge_softmax_fwd_compiles(one_chip):
     _compile(ops._edge_softmax_planned,
              _spec(one_chip, (E, HEADS)),
              _spec(one_chip, (E, HEADS, HEAD_DIM)),
-             _spec(one_chip, (NB, L_PAD), jnp.int32),
-             _spec(one_chip, (NB, L_PAD), jnp.int32),
+             _spec(one_chip, (N_CHUNKS, BLOCK_E), jnp.int32),
+             _spec(one_chip, (N_CHUNKS, BLOCK_E), jnp.int32),
              num_segments=N, block_n=BLOCK_N, block_e=BLOCK_E,
              interpret=False)
+
+
+@pytest.mark.parametrize("kernel", ["softmax", "sum", "max"])
+@pytest.mark.parametrize("rung", RUNGS, ids=lambda r: f"{r[0]}x{r[1]}")
+def test_packed_fwd_kernels_compile_at_bucket_rungs(one_chip, rung, kernel):
+    n_pad, e_pad = rung
+    chunks = _spec(one_chip, (ops.bucket_plan_chunks(n_pad, e_pad), BLOCK_E),
+                   jnp.int32)
+    geometry = dict(num_segments=n_pad, block_n=BLOCK_N, block_e=BLOCK_E,
+                    interpret=False)
+    if kernel == "softmax":
+        _compile(ops._edge_softmax_planned,
+                 _spec(one_chip, (e_pad, HEADS)),
+                 _spec(one_chip, (e_pad, HEADS, HEAD_DIM)), chunks, chunks,
+                 **geometry)
+    else:
+        _compile(ops._segment_reduce_planned, _spec(one_chip, (e_pad, D)),
+                 chunks, chunks, op=kernel, **geometry)
 
 
 def test_edge_softmax_bwd_compiles(one_chip):
