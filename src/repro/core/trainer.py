@@ -610,8 +610,14 @@ class Trainer(BaseTrainer):
         # shard staging retries transient device_put failures when a
         # runtime is configured (engine-side hook)
         rt = self.runtime
-        stage = lambda v: self.engine.stage_view(  # noqa: E731
-            shard_view(self.plan, v), retry=rt)
+
+        def stage(v):
+            with span("engine.shard"):
+                arrays = shard_view(self.plan, v)
+            with span("engine.stage"):
+                annotate(staged_bytes=sum(a.nbytes for a in arrays.values()))
+                return self.engine.stage_view(arrays, retry=rt)
+
         if self._donate_views:
             # donated buffers are consumed by the step — always restage
             return stage

@@ -76,12 +76,18 @@ class Graph:
         return self.dst[order[indptr[u]:indptr[u + 1]]]
 
     def gcn_norm(self) -> np.ndarray:
-        """Per-edge symmetric GCN normalization 1/sqrt(d_i d_j) with
-        self-loop-augmented degrees (Kipf & Welling). Cached — the edge
-        set never changes, so every view/block of this graph shares one
-        (M,) array (compact views gather slices of it per batch)."""
+        """Per-edge symmetric GCN normalization 1/sqrt(d_i d_j), the
+        entries of D^-1/2 Â D^-1/2 (Kipf & Welling), with d the in-degree
+        over this graph's own edges. GCN expects Â = A + I as edges: named
+        datasets get their self-loops in ``api._resolve_graph``, and a
+        caller-supplied graph is trusted as it stands, so nothing is added
+        here for a loop that is not an edge. A node with no in-edges
+        counts as degree 1, so its out-edges keep a finite weight. Cached
+        — the edge set never changes, so every view/block of this graph
+        shares one (M,) array (compact views gather slices of it per
+        batch)."""
         if self._gcn_norm is None:
-            deg = self.in_degree().astype(np.float64) + 1.0
+            deg = np.maximum(self.in_degree(), 1).astype(np.float64)
             self._gcn_norm = (
                 1.0 / np.sqrt(deg[self.src] * deg[self.dst])).astype(
                 np.float32)

@@ -30,7 +30,8 @@ There is no exporter: the profiler's ``.xplane.pb`` is the export, and
 Span names are stable; tools that read the buffer key on them:
 
 - training (``core/trainer.py``): ``train.fit``, ``train.view_wait``,
-  ``train.backpressure``, ``train.dispatch``;
+  ``train.backpressure``, ``train.dispatch``; the engine ``Trainer``'s
+  per-view ``engine.shard`` and ``engine.stage``;
 - view building (``runtime/prefetch.py``, ``core/views.py``):
   ``prefetch.build``, ``view.sample``, ``view.stage``;
 - serving (``serving/server.py``): ``serve.collect``, ``serve.batch``
@@ -39,8 +40,8 @@ Span names are stable; tools that read the buffer key on them:
   ``serve.gather``, ``serve.respond``.
 
 Counters set by :func:`annotate`: ``steps`` on ``train.fit``,
-``plan_lanes`` and ``live_edges`` on ``view.stage``, ``misses`` on
-``serve.batch``.
+``plan_lanes`` and ``live_edges`` on ``view.stage``, ``staged_bytes`` on
+``engine.stage``, ``misses`` on ``serve.batch``.
 """
 from __future__ import annotations
 

@@ -21,8 +21,10 @@ import numpy as np
 import pytest
 
 from repro.config import GNNConfig
-from repro.core.strategies import strategy_views
-from repro.core.trainer import CompactTrainer
+from repro.core.engine import HybridParallelEngine
+from repro.core.partition import build_partitions
+from repro.core.strategies import shard_view, strategy_views
+from repro.core.trainer import CompactTrainer, Trainer
 from repro.core.views import CompactBlockBuilder, ViewBuilder
 from repro.graph import sbm_graph
 from repro.models import make_gnn
@@ -269,6 +271,36 @@ def test_view_stage_counts_plan_lanes_and_live_edges(tmp_path):
     assert plan.gather_idx.size == plan.block_e * bucket_plan_chunks(
         plan.num_segments, plan.num_edges, plan.block_n, plan.block_e)
     assert stage.attrs["live_edges"] == len(view.dst_local)
+
+
+@pytest.mark.parametrize("strategy,stagings", [("global", 1), ("mini", 3)])
+def test_engine_trainer_spans_its_staging(tmp_path, strategy, stagings):
+    """The engine ``Trainer`` shards and stages each view inside its
+    ``prefetch.build``; ``staged_bytes`` is what the staging puts on the
+    device. Without donation (the CPU) the static global view is staged
+    once a fit, a mini-batch stream once a step."""
+    g = _graph(seed=3)
+    sg = build_partitions(g, 1)
+    tr = Trainer(HybridParallelEngine(_model(), sg), adam(1e-2), seed=0)
+    views = lambda: strategy_views(g, strategy, 2, seed=1,  # noqa: E731
+                                   batch_nodes=24)
+    # one worker: two may both miss the global view's cache and stage it
+    tr.fit(views(), steps=1, prefetch_workers=1)        # compile outside
+    with profiling(tmp_path):
+        tr.fit(views(), steps=3, prefetch_workers=1)
+    recs = timing.recorded()
+    builds = {r.id: r for r in by_name(recs, "prefetch.build")}
+    assert len(builds) == 3
+    shards = by_name(recs, "engine.shard")
+    stages = by_name(recs, "engine.stage")
+    assert len(shards) == len(stages) == stagings
+    for shard, stage in zip(sorted(shards, key=lambda r: r.start_ns),
+                            sorted(stages, key=lambda r: r.start_ns)):
+        assert shard.parent == stage.parent and shard.parent in builds
+        assert shard.end_ns <= stage.start_ns
+        view = views().build(builds[stage.parent].attrs["view"])
+        want = sum(a.nbytes for a in shard_view(sg.plan, view).values())
+        assert stage.attrs == {"staged_bytes": want}
 
 
 @pytest.fixture(scope="module")
