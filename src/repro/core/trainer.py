@@ -71,6 +71,7 @@ from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.core.aggregate import backend_name
 from repro.core.strategies import GraphView, shard_view
 from repro.core.views import CompactBlockBuilder, ViewStream
+from repro.kernels.backward import launch_tally
 from repro.runtime.faults import (DivergenceError, FaultInjector,
                                   FaultPolicy, Retrier, sync_with_timeout)
 from repro.runtime.prefetch import StreamPrefetcher, ViewPrefetcher
@@ -237,6 +238,9 @@ class BaseTrainer:
         # the stream itself instead of asking the caller to)
         self.view_cursor = 0
         self._resume_cursor: Optional[int] = None
+        # backward Sum-stage launches by path, per compiled step (set
+        # while the step traces; see bwd_launch_counts)
+        self.bwd_launches: dict = {}
 
     # -- subclass hooks --------------------------------------------------------
 
@@ -322,15 +326,25 @@ class BaseTrainer:
         the executor more than the overlap buys.
 
         The call is one ``train.fit`` span, whose ``steps`` counts the
-        steps it returns losses for.
+        steps it returns losses for, and whose ``bwd_vmem`` and
+        ``bwd_dma`` are :meth:`bwd_launch_counts`.
         """
         with span("train.fit"):
             out = self._fit(views, steps, prefetch, prefetch_workers,
                             prefetch_mode, eval_every, eval_view, eval_mask,
                             checkpoint_every, checkpoint_dir, max_in_flight,
                             log_every, log, resume)
-            annotate(steps=len(out["losses"]))
+            annotate(steps=len(out["losses"]), **self.bwd_launch_counts())
             return out
+
+    def bwd_launch_counts(self) -> dict:
+        """The backward Sum-stage launches of the trainer's compiled
+        steps by path (``kernels/backward.py``): ``bwd_vmem`` read a
+        VMEM-resident node table, ``bwd_dma`` one row DMA per lane;
+        summed over the executables (one a bucket for the bucketed
+        trainer), as counted when each traced."""
+        return {k: sum(c[k] for c in self.bwd_launches.values())
+                for k in ("bwd_vmem", "bwd_dma")}
 
     def _fit(self, views, steps, prefetch, prefetch_workers, prefetch_mode,
              eval_every, eval_view, eval_mask, checkpoint_every,
@@ -581,7 +595,9 @@ class Trainer(BaseTrainer):
             # runs only while tracing — this is the compile counter the
             # compiled-once contract is certified against
             self.trace_counts["train_step"] += 1
-            loss, grads = lg(params, data, view)
+            with launch_tally() as launches:
+                loss, grads = lg(params, data, view)
+            self.bwd_launches["train_step"] = launches
             new_params, new_state = opt.update(grads, opt_state, params)
             return new_params, new_state, loss
 
@@ -714,13 +730,13 @@ class Trainer(BaseTrainer):
         body (the compile counter), so the counters are saved/restored:
         analysis must not break the compiled-once certificate."""
         staged = self.engine.stage_view(shard_view(self.plan, view))
-        saved = dict(self.trace_counts)
+        saved = dict(self.trace_counts), dict(self.bwd_launches)
         try:
             return jax.make_jaxpr(self._step)(
                 self.params, self.opt_state, self.engine._device_data,
                 staged)
         finally:
-            self.trace_counts = saved
+            self.trace_counts, self.bwd_launches = saved
 
     def traced_infer_jaxpr(self, view: GraphView):
         """Jaxpr of the jitted eval/infer computation over ``view``."""
@@ -778,8 +794,11 @@ class CompactTrainer(BaseTrainer):
         def _step(params, opt_state, block):
             # runs only while tracing: one increment per (bucket) compile
             self.trace_counts["train_step"] += 1
-            loss, grads = jax.value_and_grad(
-                lambda p: loss_block(model, p, block))(params)
+            with launch_tally() as launches:
+                loss, grads = jax.value_and_grad(
+                    lambda p: loss_block(model, p, block))(params)
+            self.bwd_launches[(block.x.shape[0], block.src.shape[0])] = \
+                launches
             new_params, new_state = opt.update(grads, opt_state, params)
             return new_params, new_state, loss
 
@@ -860,6 +879,7 @@ class CompactTrainer(BaseTrainer):
         once-per-bucket certificate."""
         saved_counts = dict(self.trace_counts)
         saved_buckets = set(self.buckets_touched)
+        saved_launches = dict(self.bwd_launches)
         try:
             block = self._prepare(view)
             return jax.make_jaxpr(self._step)(
@@ -867,3 +887,4 @@ class CompactTrainer(BaseTrainer):
         finally:
             self.trace_counts = saved_counts
             self.buckets_touched = saved_buckets
+            self.bwd_launches = saved_launches

@@ -20,9 +20,12 @@ from repro.analysis.jaxpr import (ContractError, JaxprContext,  # noqa: F401
                                   count_segment_scatters,  # noqa: F401
                                   jaxpr_avals, jaxpr_eqns,  # noqa: F401
                                   run_rules)
-from repro.kernels.backward import (edge_softmax_bwd_csc,
+from repro.kernels.backward import (count_launch, edge_softmax_bwd_csc,
+                                    edge_softmax_bwd_path,
                                     segment_max_bwd_csc,
-                                    segment_sum_bwd_csc)
+                                    segment_max_bwd_path,
+                                    segment_sum_bwd_csc,
+                                    segment_sum_bwd_path)
 from repro.kernels.segment_sum import segment_sum_csc, segment_max_csc
 from repro.kernels.wkv6 import wkv6 as _wkv6_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
@@ -267,6 +270,8 @@ def segment_sum_bwd_op(g: jax.Array, plan: CSCPlan,
         raise ValueError(f"cotangent segment axis {g.shape[0]} != plan "
                          f"num_segments {plan.num_segments}")
     flat, trailing = _reshape_to_2d(g)
+    if plan.num_edges:
+        count_launch(segment_sum_bwd_path(*flat.shape, plan.block_e))
     out = _segment_sum_bwd_planned(flat, jnp.asarray(plan.edge_dst),
                                    plan.num_edges, plan.block_e,
                                    _interp(interpret))
@@ -295,6 +300,8 @@ def segment_max_bwd_op(g: jax.Array, fwd_out: jax.Array, data: jax.Array,
     gf, trailing = _reshape_to_2d(g)
     ff, _ = _reshape_to_2d(fwd_out)
     df, _ = _reshape_to_2d(data)
+    if plan.num_edges:
+        count_launch(segment_max_bwd_path(*gf.shape, plan.block_e))
     out = _segment_max_bwd_planned(gf, ff, df, jnp.asarray(plan.edge_dst),
                                    plan.num_edges, plan.block_e,
                                    _interp(interpret))
@@ -504,6 +511,8 @@ def edge_softmax_bwd_op(g: jax.Array, logits: jax.Array, values: jax.Array,
     # og_i = out_i . g_i: the node-proportional contraction of d_logit
     # (elementwise jnp, no segment op, no edge-axis materialization)
     og = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+    if plan.num_edges:
+        count_launch(edge_softmax_bwd_path(*g.shape, plan.block_e))
     d_logits, d_values = _edge_softmax_bwd_planned(
         g, lg, vals, m, den, og, jnp.asarray(plan.edge_dst),
         plan.num_edges, plan.block_e, _interp(interpret))

@@ -95,24 +95,45 @@ word a chunk and a block; the VMEM footprint does not.
 Backward geometry (kernels in backward.py)
 ------------------------------------------
 The backward kernels run over the **edge axis** (grid ``(d_tiles,
-E_pad/BE)``; softmax: ``(E_pad/BE,)`` with heads unrolled in the body).
-The node-indexed arrays they read (cotangent ``g``, saved forward
-output, softmax stats) stay in HBM and are gathered per edge through the
-plan's inverse map ``edge_dst``, per grid step in f32:
+E_pad/BE)``; softmax: ``(E_pad/BE,)`` with heads unrolled in the body)
+and fill a ``(BE, BD)`` buffer with each edge's row of a node-indexed
+table (cotangent ``g``, saved forward output, softmax stats) through the
+plan's inverse map ``edge_dst``. Where the table fits, it is **resident**
+in VMEM for the whole launch and each lane is a row copy
+(:func:`copy_rows`); where it does not, each live lane's row is one HBM
+row DMA (:func:`gather_rows`). Per launch, in f32:
 
 =====================  =======================  =========================
 buffer                 shape                    bytes (defaults)
 =====================  =======================  =========================
+node table, resident   (N, BD) per table, one   4·N·BD (gcn-reddit:
+                       buffer, read once per    14,560·128 → 7.45 MB)
+                       d-tile
+node table, DMA path   (N, 1, Dp) in HBM        0 (never resident)
 edge_dst chunk (SMEM)  (1, BE) int32            4·BE
 edge_dst column        (BE, 1) int32 block      4·BE (lane-padded: 128 KiB)
-gathered rows          (BE, BD) per operand     4·BE·BD (max: 2 operands)
+gathered rows          (BE, BD) per table       4·BE·BD (max: 2 tables)
 edge tiles             (BE, BD) in + out        2·4·BE·BD
 =====================  =======================  =========================
 
-The softmax backward gathers the ``(N, H·D)`` cotangent rows and one
-packed row of per-destination stats ``[m | den | out·g]`` per edge, and
-rebuilds the edge probability in registers from the saved logits — it is
-never written to HBM.
+The size rule depends on the shape alone: a launch is resident when its
+tables' lane-padded bytes, plus the ``edge_dst`` column and the edge
+tiles, buffers and largest temporary, fit the analyzer's 16 MiB budget
+(``backward.VMEM_BUDGET``); resident launches raise Mosaic's scoped VMEM
+to 32 MiB for the pipeline's second buffers. At the benchmark's widths
+the GCN cell and the bucket rungs up to (16,384, 65,536) are resident;
+the (32,768, 131,072) rung and larger worker shares take the DMA path.
+On a v5e the DMA path compiles only for 128-lane rows (``BD`` above 128
+is refused: a one-row DMA may not cut the buffer's (8, 128) tiles); the
+resident path copies rows of any width.
+
+The softmax backward's table is one packed row per node, ``[g (H·D) | m
+| den | out·g]`` lane-padded, so a lane takes one row, not two (GAT-E's 4
+heads of 8: 44 lanes in one 128-lane row, an 8 MiB table at its training
+rung); the DMA path gathers it 128 lanes at a time, since a one-row DMA
+may not cut a wider buffer's (8, 128) tiles. The kernel rebuilds the
+edge probability in registers from the saved logits — it is never
+written to HBM.
 
 Host-side planning (``build_csc_plan`` in ops.py) computes the packed
 chunk layout once per graph — the paper's "reused CSR/CSC indexing"
@@ -209,6 +230,26 @@ def gather_rows(idx_smem, src_hbm, buf, sem, num_rows: int, d0, width: int,
 
     jax.lax.fori_loop(0, buf.shape[0], start, 0)
     jax.lax.fori_loop(0, buf.shape[0], wait, 0)
+
+
+def copy_rows(idx_smem, table, buf, num_rows: int):
+    """``buf[i] = table[min(idx_smem[0, i], num_rows - 1)]`` for every
+    lane ``i``: the VMEM-resident counterpart of :func:`gather_rows`, one
+    row copy at a dynamic sublane offset per lane, no DMA. Lanes without a
+    row (``idx >= num_rows``) copy the last one, so consumers mask them."""
+    def copy(i):
+        row = jnp.minimum(idx_smem[0, i], num_rows - 1)
+        buf[pl.ds(i, 1), :] = table[pl.ds(row, 1), :]
+
+    def trip(j, carry):
+        for k in range(8):                    # unrolled by hand, 8 a trip
+            copy(j * 8 + k)
+        return carry
+
+    lanes = buf.shape[0]
+    jax.lax.fori_loop(0, lanes // 8, trip, 0)
+    for i in range(lanes - lanes % 8, lanes):     # a tail of under 8
+        copy(i)
 
 
 def _check_plan(gather_idx, local_ids, block_e: int) -> int:

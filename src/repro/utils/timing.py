@@ -39,9 +39,10 @@ Span names are stable; tools that read the buffer key on them:
   ``serve.stage``, ``serve.device``, ``serve.writeback``,
   ``serve.gather``, ``serve.respond``.
 
-Counters set by :func:`annotate`: ``steps`` on ``train.fit``,
-``plan_lanes`` and ``live_edges`` on ``view.stage``, ``staged_bytes`` on
-``engine.stage``, ``misses`` on ``serve.batch``.
+Counters set by :func:`annotate`: ``steps``, ``bwd_vmem`` and
+``bwd_dma`` on ``train.fit``, ``plan_lanes`` and ``live_edges`` on
+``view.stage``, ``staged_bytes`` on ``engine.stage``, ``misses`` on
+``serve.batch``.
 """
 from __future__ import annotations
 

@@ -262,6 +262,34 @@ def test_srclint_unjoined_process_waiver():
     assert lint_source(src, "fixture.py") == []
 
 
+@pytest.mark.parametrize("kernel,n,e_pad,heads,head_dim", [
+    ("sum", 14560, 6834176, 1, 128),        # gcn-reddit's node table
+    ("softmax", 16384, 65536, 4, 8),        # gat_e-alipay's training rung
+], ids=["gcn-reddit", "gat_e-mini-train"])
+def test_vmem_budget_passes_resident_backward_launches(kernel, n, e_pad,
+                                                       heads, head_dim):
+    """The backward kernels at the training cells' shapes keep their
+    whole node table in VMEM (a block of all ``n`` rows), and the
+    launch, table included, stays within the default budget."""
+    from repro.kernels.backward import (edge_softmax_bwd_csc,
+                                        segment_sum_bwd_csc)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa
+    edge_dst = jax.ShapeDtypeStruct((e_pad,), jnp.int32)
+    if kernel == "sum":
+        jx = jax.make_jaxpr(lambda g, ed: segment_sum_bwd_csc(
+            g, ed, e_pad - 100))(f32(n, head_dim), edge_dst)
+    else:
+        jx = jax.make_jaxpr(lambda *a: edge_softmax_bwd_csc(*a, e_pad))(
+            f32(n, heads, head_dim), f32(e_pad, heads),
+            f32(e_pad, heads, head_dim), f32(n, heads), f32(n, heads),
+            f32(n, heads), edge_dst)
+    (st,) = iter_kernel_stats(jx)
+    tables = [b for b in st.blocks
+              if b["memory_space"] == "vmem" and b["block_shape"][0] == n]
+    assert len(tables) == 1 and tables[0]["bytes"] >= n * 128 * 4
+    assert check_vmem(jx) == []
+
+
 # ---------------------------------------------------------------------------
 # zero findings on the real thing
 # ---------------------------------------------------------------------------

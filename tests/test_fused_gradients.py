@@ -6,20 +6,44 @@ backend, plus the fused-path memory contracts:
   csc path (the fused backward kernels of kernels/backward.py), asserted
   via ``assert_sum_stage_fused`` on value_and_grad jaxprs
 
-Covers multi-head messages, empty segments, masked edges, and D > 64
-(the d-tiled grid axes), for every combine mode the kernels accelerate."""
+Covers multi-head messages, empty segments, masked edges, pad lanes and
+D > 64 (the d-tiled grid axes), for every combine mode the kernels
+accelerate, with the backward kernels on both of their paths: the node
+table resident in VMEM, and one row DMA per lane (forced by a zero
+budget); the two give the same bits."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.aggregate import combine
+from repro.kernels import backward
 from repro.kernels.ops import (assert_pregather_free,
                                assert_sum_stage_fused, build_csc_plan,
                                count_segment_scatters, edge_softmax_op,
                                segment_max_op, segment_sum_op)
 
 KERNEL_MODES = ["sum", "max", "softmax"]
+PATHS = ["vmem", "dma"]
+
+
+def _grads_on(path, monkeypatch, loss, *args):
+    """``jax.grad`` of ``loss`` with the backward kernels on ``path``; the
+    tally shows each launch took it. jit caches key on shapes, not on the
+    budget, so they are cleared on both sides."""
+    monkeypatch.setattr(backward, "VMEM_BUDGET",
+                        backward.DEFAULT_VMEM_BUDGET if path == "vmem" else 0)
+    jax.clear_caches()
+    with backward.launch_tally() as launches:
+        grads = jax.grad(loss, argnums=tuple(range(len(args))))(*args)
+    jax.clear_caches()
+    assert launches[f"bwd_{path}"] > 0
+    assert launches[f"bwd_{_other(path)}"] == 0
+    return [np.asarray(a) for a in grads]
+
+
+def _other(path):
+    return PATHS[1 - PATHS.index(path)]
 
 
 def _problem(seed, E=400, N=90, H=2, D=8, mask_frac=0.3):
@@ -32,12 +56,16 @@ def _problem(seed, E=400, N=90, H=2, D=8, mask_frac=0.3):
     return msg, jnp.asarray(ids), ids, mask
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("mode", KERNEL_MODES)
 @pytest.mark.parametrize("H,D", [(1, 8), (3, 16), (2, 80)])
-def test_fused_kernel_gradient_parity(mode, H, D):
+def test_fused_kernel_gradient_parity(mode, H, D, path, monkeypatch):
     """csc grads == reference grads for multi-head messages, masked edges,
-    empty segments; (2, 80) folds to lane width 160 > 64, exercising the
-    d-tiled max kernel (both the max combine and softmax's max pass)."""
+    empty segments and pad lanes (400 edges in 64-lane chunks), with the
+    backward on ``path``, and the same bits on the other path; (2, 80)
+    folds to lane width 160 > 64, exercising the d-tiled max kernel (both
+    the max combine and softmax's max pass) and a packed softmax row
+    wider than one 128-lane tile."""
     msg, dst, ids_np, mask = _problem(seed=11 + H + D, H=H, D=D)
     N = 90
     plan = build_csc_plan(ids_np, N, block_n=32, block_e=64)
@@ -49,18 +77,22 @@ def test_fused_kernel_gradient_parity(mode, H, D):
 
     g_ref = jax.grad(loss, argnums=(0, 1))(msg["value"], msg["logit"],
                                            "reference", None)
-    g_csc = jax.grad(loss, argnums=(0, 1))(msg["value"], msg["logit"],
-                                           "csc", plan)
-    for name, a, b in zip(("value", "logit"), g_ref, g_csc):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=1e-4, atol=1e-5,
+    csc = lambda v, lg: loss(v, lg, "csc", plan)  # noqa: E731
+    g_csc = _grads_on(path, monkeypatch, csc, msg["value"], msg["logit"])
+    g_other = _grads_on(_other(path), monkeypatch, csc, msg["value"],
+                        msg["logit"])
+    for name, a, b, c in zip(("value", "logit"), g_ref, g_csc, g_other):
+        np.testing.assert_allclose(b, np.asarray(a), rtol=1e-4, atol=1e-5,
                                    err_msg=f"{mode}/{name}")
+        np.testing.assert_array_equal(b, c, err_msg=f"{mode}/{name}")
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("mode", KERNEL_MODES)
-def test_fused_kernel_gradient_all_masked(mode):
+def test_fused_kernel_gradient_all_masked(mode, path, monkeypatch):
     """Gradients through a fully masked combine stay finite (no NaN from
-    empty-segment softmax or NEG max identities)."""
+    empty-segment softmax or NEG max identities) on either backward path,
+    and agree to the bit."""
     msg, dst, ids_np, _ = _problem(seed=5, H=2, D=8)
     N = 90
     mask = jnp.zeros(ids_np.shape[0], jnp.float32)
@@ -71,9 +103,12 @@ def test_fused_kernel_gradient_all_masked(mode):
                       backend="csc", plan=plan)
         return jnp.sum(out * out)
 
-    g = jax.grad(loss, argnums=(0, 1))(msg["value"], msg["logit"])
-    for arr in g:
-        assert np.all(np.isfinite(np.asarray(arr))), mode
+    g = _grads_on(path, monkeypatch, loss, msg["value"], msg["logit"])
+    g_other = _grads_on(_other(path), monkeypatch, loss, msg["value"],
+                        msg["logit"])
+    for arr, other in zip(g, g_other):
+        assert np.all(np.isfinite(arr)), mode
+        np.testing.assert_array_equal(arr, other, err_msg=mode)
 
 
 # ---------------------------------------------------------------------------

@@ -291,10 +291,12 @@ def test_plan_edge_dst_inverts_the_plan():
 
 @pytest.mark.parametrize("E,N,D,blocks", [(400, 90, 8, (32, 64)),
                                           (777, 300, 48, (64, 128)),
-                                          (300, 64, 160, (16, 64))])
+                                          (300, 64, 160, (16, 64)),
+                                          (100, 40, 8, (16, 20))])
 def test_segment_sum_bwd_kernel(E, N, D, blocks):
     """d_data[e] = g[dst[e]] via the plan-driven gather kernel (D=160
-    exercises the backward d-tiling)."""
+    exercises the backward d-tiling; 20-lane chunks, a resident table's
+    row copies past the last whole trip of 8)."""
     from repro.kernels.ops import segment_sum_bwd_op
     rng = np.random.default_rng(E + D)
     ids = rng.integers(0, N, E).astype(np.int32)

@@ -241,7 +241,8 @@ def test_fit_records_its_steps(tmp_path):
         tr.fit(_mini(g, seed=1), steps=3, prefetch_workers=2)
     recs = timing.recorded()
     (fit,) = by_name(recs, "train.fit")
-    assert fit.attrs == {"steps": 3}
+    # the reference backend runs no backward kernel
+    assert fit.attrs == {"steps": 3, "bwd_vmem": 0, "bwd_dma": 0}
     waits = children(recs, fit, "train.view_wait")
     assert sorted(r.attrs["view"] for r in waits) == [0, 1, 2]
     assert len(children(recs, fit, "train.dispatch")) == 3
@@ -301,6 +302,54 @@ def test_engine_trainer_spans_its_staging(tmp_path, strategy, stagings):
         view = views().build(builds[stage.parent].attrs["view"])
         want = sum(a.nbytes for a in shard_view(sg.plan, view).values())
         assert stage.attrs == {"staged_bytes": want}
+
+
+def _gcn_engine_trainer():
+    g = _graph(seed=4)
+    model = make_gnn(GNNConfig(model="gcn", num_layers=2, hidden_dim=16,
+                               num_classes=4, feature_dim=8,
+                               aggregate_backend="csc"))
+    tr = Trainer(HybridParallelEngine(model, build_partitions(g, 1)),
+                 adam(1e-2), seed=0)
+    return tr, lambda: strategy_views(g, "global", 2, seed=1)
+
+
+def _gat_e_compact_trainer():
+    from repro.graph import make_dataset
+    g = make_dataset("alipay_like", num_nodes=300, seed=0)
+    model = make_gnn(GNNConfig(
+        model="gat_e", num_layers=2, hidden_dim=16, num_classes=2,
+        feature_dim=g.node_features.shape[1], num_heads=4,
+        edge_feature_dim=g.edge_features.shape[1], aggregate_backend="csc"))
+    tr = CompactTrainer(model, g, adam(1e-2), seed=0, gcn_norm=False)
+    return tr, lambda: strategy_views(g, "mini", 2, seed=0, batch_nodes=24,
+                                      neighbor_cap=4, compact=True)
+
+
+@pytest.mark.parametrize("path", ["vmem", "dma"])
+@pytest.mark.parametrize("make", [_gcn_engine_trainer,
+                                  _gat_e_compact_trainer],
+                         ids=["engine_gcn", "compact_gat_e"])
+def test_fit_counts_backward_launches_by_path(tmp_path, monkeypatch, make,
+                                              path):
+    """``train.fit`` carries the backward Sum-stage launches of the
+    compiled step by path: two layers, two launches (segment sums in the
+    engine's GCN, edge softmaxes in the compact GAT-E), all resident at
+    these sizes, or all on row DMAs under a zero budget."""
+    from repro.kernels import backward
+    if path == "dma":
+        monkeypatch.setattr(backward, "VMEM_BUDGET", 0)
+    jax.clear_caches()          # jit keys on shapes, not on the budget
+    tr, views = make()
+    try:
+        tr.fit(views(), steps=1, prefetch_workers=1)     # compile outside
+        with profiling(tmp_path):
+            tr.fit(views(), steps=2, prefetch_workers=1)
+    finally:
+        jax.clear_caches()
+    (fit,) = by_name(timing.recorded(), "train.fit")
+    other = "dma" if path == "vmem" else "vmem"
+    assert fit.attrs == {"steps": 2, f"bwd_{path}": 2, f"bwd_{other}": 0}
 
 
 @pytest.fixture(scope="module")

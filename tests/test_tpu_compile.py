@@ -17,6 +17,7 @@ The topology is described inside a module fixture: the TPU compiler's
 library may be loaded by one process at a time, so the description must
 happen only in the test process that runs these tests.
 """
+import functools
 import os
 
 import jax
@@ -24,7 +25,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops
+from repro.analysis.vmem import iter_kernel_stats
+from repro.kernels import backward, ops
 
 # alipay_like (powerlaw_graph defaults): nodes, directed edges, and its
 # packed CSC plan geometry at the default blocks (128 x 256)
@@ -34,6 +36,10 @@ BLOCK_N, BLOCK_E = 128, 256
 HEADS, HEAD_DIM, D = 4, 32, 128
 # (n_pad, e_pad) bucket rungs: mini-batch training's, and serving's top
 RUNGS = [(16384, 65536), (32768, 131072)]
+# the benchmark's training cells: gcn-reddit's node table and padded edge
+# axis at hidden 128; gat_e-alipay's 4 heads of 8
+GCN_N, GCN_E, GCN_E_PAD = 14560, 6833994, 6834176
+GAT_HEADS, GAT_HEAD_DIM = 4, 8
 
 
 @pytest.fixture(scope="module")
@@ -129,3 +135,57 @@ def test_edge_softmax_bwd_compiles(one_chip):
              _spec(one_chip, (N, HEADS)),
              _spec(one_chip, (E_PAD,), jnp.int32),
              num_edges=E, block_e=BLOCK_E, interpret=False)
+
+
+def _softmax_bwd_args(sharding, n, e, e_pad, heads, head_dim):
+    return (_spec(sharding, (n, heads, head_dim)),
+            _spec(sharding, (e, heads)),
+            _spec(sharding, (e, heads, head_dim)),
+            _spec(sharding, (n, heads)), _spec(sharding, (n, heads)),
+            _spec(sharding, (n, heads)),
+            _spec(sharding, (e_pad,), jnp.int32))
+
+
+# (kernel, node rows, edges, padded edges, heads, head width, path): the
+# GCN cell's table (7.45 MB), the mini-train rung's packed softmax rows
+# (8 MiB), and the top rung's (16 MiB, over the budget with its tiles)
+BWD_CASES = [
+    ("sum", GCN_N, GCN_E, GCN_E_PAD, 1, D, "vmem"),
+    ("softmax", *RUNGS[0], RUNGS[0][1], GAT_HEADS, GAT_HEAD_DIM, "vmem"),
+    ("softmax", *RUNGS[1], RUNGS[1][1], GAT_HEADS, GAT_HEAD_DIM, "dma"),
+]
+
+
+def _bwd_launch(kernel, sharding, n, e, e_pad, heads, head_dim):
+    """The jitted backward wrapper and its arguments at one shape."""
+    if kernel == "sum":
+        return (ops._segment_sum_bwd_planned,
+                (_spec(sharding, (n, heads * head_dim)),
+                 _spec(sharding, (e_pad,), jnp.int32)))
+    return (ops._edge_softmax_bwd_planned,
+            _softmax_bwd_args(sharding, n, e, e_pad, heads, head_dim))
+
+
+def _resident_tables(closed_jaxpr, n: int) -> int:
+    """VMEM blocks of the launch's kernels that hold all ``n`` rows."""
+    return sum(b["memory_space"] == "vmem" and b["block_shape"][0] == n
+               for s in iter_kernel_stats(closed_jaxpr) for b in s.blocks)
+
+
+@pytest.mark.parametrize("case", BWD_CASES,
+                         ids=lambda c: f"{c[0]}-{c[1]}-{c[6]}")
+def test_bwd_kernels_compile_on_their_path(one_chip, case):
+    """The backward kernels at the benchmark's training shapes compile
+    with the node table resident where it fits the VMEM budget, and with
+    one row DMA per lane above it."""
+    kernel, n, e, e_pad, heads, head_dim, path = case
+    if kernel == "sum":
+        assert backward.segment_sum_bwd_path(n, head_dim, BLOCK_E) == path
+    else:
+        assert backward.edge_softmax_bwd_path(n, heads, head_dim,
+                                              BLOCK_E) == path
+    fn, args = _bwd_launch(kernel, one_chip, n, e, e_pad, heads, head_dim)
+    static = dict(num_edges=e, block_e=BLOCK_E, interpret=False)
+    jx = jax.make_jaxpr(functools.partial(fn, **static))(*args)
+    assert _resident_tables(jx, n) == (path == "vmem")
+    _compile(fn, *args, **static)
